@@ -1,8 +1,8 @@
 """Persistent AOT executable cache (docs/design.md §31).
 
-BENCH_r03: 41 s compile+first-run against a 65 ms steady-state drain —
-at serving scale interactive p99 is compile-bound, not execution-bound.
-This module eliminates the cold start by serializing compiled fusion
+A first request that compiles pays seconds against a steady-state drain
+of milliseconds — at serving scale interactive p99 is compile-bound, not
+execution-bound.  This module eliminates the cold start by serializing compiled fusion
 runners (``jax.experimental.serialize_executable``) to a content-hashed
 on-disk cache keyed by the FULL semantic identity the plan layer
 already computes, so a fresh process (or a fresh replica, or the
@@ -371,7 +371,17 @@ def amps_struct(num_amps: int, batch: int, dtype, mesh):
     the SAME aval (shape, dtype, sharding) a live drain dispatches, so
     a prewarm from analytic shapes produces the key and executable the
     live request then hits."""
-    shape = (batch, 2, num_amps) if batch else (2, num_amps)
+    if batch:
+        shape = (batch, 2, num_amps)
+    else:
+        from .qureg import device_amps_shape
+
+        nsh = 0
+        if mesh is not None and num_amps >= mesh.devices.size:
+            from .parallel import dist as PAR
+
+            nsh = PAR.num_shard_bits(mesh)
+        shape = device_amps_shape(num_amps.bit_length() - 1, nsh)
     sharding = None
     if mesh is not None:
         from jax.sharding import NamedSharding

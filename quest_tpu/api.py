@@ -124,7 +124,8 @@ def createQureg(numQubits: int, env: _env.QuESTEnv) -> Qureg:
     V.validate_num_qubits(numQubits, "createQureg", num_ranks=env.num_ranks)
     q = Qureg(numQubits, env, is_density_matrix=False)
     _gov.admit_new(q, "createQureg")
-    q.amps = q.device_put(K.init_zero_state(q.num_amps_total, q.dtype))
+    _init_state(q, "basis", 0,
+                lambda: K.init_zero_state(q.num_amps_total, q.dtype))
     return q
 
 
@@ -384,19 +385,30 @@ def createDiagonalOpFromPauliHamilFile(filename: str, env: _env.QuESTEnv) -> Dia
 # ---------------------------------------------------------------------------
 
 
+def _init_state(qureg: Qureg, kind: str, x, flat_fn) -> None:
+    """Write a basis / plus / blank state: built on the device in the
+    register's device shape (Qureg.fill, old state donated), or from the
+    flat host-side kernel for a register bank."""
+    if qureg.device_shape() is None:
+        qureg.amps = qureg.device_put(flat_fn())
+    else:
+        qureg.fill(kind, x)
+
+
 def initBlankState(qureg: Qureg) -> None:
     """Set all amplitudes to zero (QuEST.h:1361)."""
-    qureg.amps = qureg.device_put(K.init_blank_state(qureg.num_amps_total, qureg.dtype))
+    _init_state(qureg, "blank", 0, lambda: K.init_blank_state(
+        qureg.num_amps_total, qureg.dtype))
 
 
 def initZeroState(qureg: Qureg) -> None:
     """Set the register to |0...0> (QuEST.h:1375)."""
     if qureg.is_density_matrix:
-        qureg.amps = qureg.device_put(
-            K.init_classical_density(qureg.num_qubits_represented, 0, qureg.dtype)
-        )
+        _init_state(qureg, "basis", 0, lambda: K.init_classical_density(
+            qureg.num_qubits_represented, 0, qureg.dtype))
     else:
-        qureg.amps = qureg.device_put(K.init_zero_state(qureg.num_amps_total, qureg.dtype))
+        _init_state(qureg, "basis", 0, lambda: K.init_zero_state(
+            qureg.num_amps_total, qureg.dtype))
     qureg.qasm_log.init_zero()
 
 
@@ -410,20 +422,23 @@ def initPlusState(qureg: Qureg) -> None:
             )
         )
     else:
-        qureg.amps = qureg.device_put(K.init_plus_state(qureg.num_amps_total, qureg.dtype))
+        _init_state(qureg, "plus", 1.0 / math.sqrt(qureg.num_amps_total),
+                    lambda: K.init_plus_state(qureg.num_amps_total,
+                                              qureg.dtype))
 
 
 def initClassicalState(qureg: Qureg, stateInd: int) -> None:
     """Set the register to a computational basis state (QuEST.h:1431)."""
     V.validate_state_index(qureg, stateInd, "initClassicalState")
     if qureg.is_density_matrix:
-        qureg.amps = qureg.device_put(
-            K.init_classical_density(qureg.num_qubits_represented, stateInd, qureg.dtype)
-        )
+        dim = 1 << qureg.num_qubits_represented
+        _init_state(qureg, "basis", stateInd * (dim + 1),
+                    lambda: K.init_classical_density(
+                        qureg.num_qubits_represented, stateInd, qureg.dtype))
     else:
-        qureg.amps = qureg.device_put(
-            K.init_classical_state(qureg.num_amps_total, stateInd, qureg.dtype)
-        )
+        _init_state(qureg, "basis", stateInd,
+                    lambda: K.init_classical_state(
+                        qureg.num_amps_total, stateInd, qureg.dtype))
 
 
 def initPureState(qureg: Qureg, pure: Qureg) -> None:
@@ -538,7 +553,7 @@ def setAmps(qureg: Qureg, startInd: int, reals, imags, numAmps: int) -> None:
     V.validate_finite(im, "setAmps")
     vals = np.stack([re, im]).astype(qureg.dtype)
     # layout-safe ranged write: tile-aligned block updates + edge tiles,
-    # never the eager .at[].set() whose gather relayouts a canonically-
+    # never the eager .at[].set() whose gather re-layouts a canonically-
     # held big state (ops/element.py)
     qureg.amps = E.set_amp_range(qureg.amps, int(startInd), vals)
 

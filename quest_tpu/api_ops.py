@@ -55,8 +55,11 @@ def calcProbOfOutcome(qureg: Qureg, measureQubit: int, outcome: int) -> float:
             qureg.amps, num_qubits=qureg.num_qubits_represented,
             target=measureQubit, outcome=outcome, quad=quad)
     else:
+        # read where the state lies: the live permutation only moves
+        # which physical bit holds the measured qubit
         p = C.calc_prob_of_outcome_statevec(
-            qureg.amps, num_qubits=_sv_n(qureg), target=measureQubit,
+            qureg.device_amps_raw(), num_qubits=_sv_n(qureg),
+            target=qureg._phys_bits((measureQubit,))[0],
             outcome=outcome, quad=quad)
     return float(p)
 
@@ -424,13 +427,18 @@ def mixMultiQubitKrausMap(qureg: Qureg, targets: Sequence[int], ops, numOps: Opt
 def getAmp(qureg: Qureg, index: int) -> complex:
     """Fetch one complex amplitude (QuEST.h:1987).  Routed through the
     layout-safe dynamic-slice kernel (ops/element.py): O(1 tile) on a
-    canonically-held big state, never a full-state relayout — matching
+    canonically-held big state, never a full-state re-layout — matching
     the reference's O(1) chunk read (QuEST_cpu_local.c:225-233)."""
     from .ops import element as E
 
     V.validate_state_vector(qureg, "getAmp")
     V.validate_num_amps(qureg, index, 1, "getAmp")
-    pair = np.asarray(E.get_amp_pair(qureg.amps, int(index)))
+    amps = qureg.device_amps_raw()
+    # a live permutation relabels the index bits: read the physical slot
+    phys = 0
+    for q, p in enumerate(qureg._phys_bits(range(_sv_n(qureg)))):
+        phys |= ((int(index) >> q) & 1) << p
+    pair = np.asarray(E.get_amp_pair(amps, phys))
     return complex(pair[0], pair[1])
 
 
@@ -475,9 +483,11 @@ def calcTotalProb(qureg: Qureg) -> float:
         return float(
             C.calc_total_prob_density(qureg.amps, num_qubits=qureg.num_qubits_represented)
         )
+    # the norm is invariant under the live permutation: no remap
+    amps = qureg.device_amps_raw()
     if _quad():
-        return float(C.calc_total_prob_statevec_quad(qureg.amps))
-    return float(C.calc_total_prob_statevec(qureg.amps))
+        return float(C.calc_total_prob_statevec_quad(amps))
+    return float(C.calc_total_prob_statevec(amps))
 
 
 def calcInnerProduct(bra: Qureg, ket: Qureg) -> complex:
@@ -632,8 +642,8 @@ def calcExpecPauliSum(qureg: Qureg, allPauliCodes, termCoeffs, workspace: Option
                 mesh=qureg.env.mesh, num_qubits=n, quad=quad)
         else:
             val = P.expec_pauli_sum_scan(
-                qureg.amps, codes_seq, jnp.asarray(cj), num_qubits=n,
-                quad=quad,
+                qureg.device_amps(), codes_seq, jnp.asarray(cj),
+                num_qubits=n, quad=quad,
             )
     return float(val)
 
@@ -1130,7 +1140,8 @@ def _qft_fused(qureg: Qureg, qubits) -> bool:
             return False
 
     shifts = [0, _shift(qureg)] if qureg.is_density_matrix else [0]
-    qureg.amps = CIRC.fused_qft(qureg.amps, nsv, start, nt, shifts=shifts)
+    qureg.amps = CIRC.fused_qft(qureg.device_amps(), nsv, start, nt,
+                                shifts=shifts)
     _qft_qasm_trail(qureg, qubits, nt)
     return True
 

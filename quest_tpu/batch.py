@@ -201,6 +201,10 @@ class BatchedQureg(Qureg):
     def device_put(self, amps):
         return jax.device_put(self._as_bank(amps), self.sharding())
 
+    def device_shape(self):
+        """A bank keeps its flat (B, 2, 2^n) shape (None: no reshape)."""
+        return None
+
     def element(self, i: int):
         """Canonical-order amplitudes of batch element ``i`` as a
         (2, 2^n) array (pending gates drain, permutation

@@ -43,7 +43,7 @@ def _guard_host_gather(qureg: Qureg, func: str) -> None:
     """Refuse to gather a full state to one host buffer beyond the
     reference's message cap (MPI_MAX_AMPS_IN_MSG — the reference's
     toQVector guard, utilities.cpp:1073-1074): at 30q+ the gather is also
-    a full-state device relayout (the round-3 OOM trap, BASELINE.md)."""
+    a full-state device layout copy, which a 30q state cannot afford."""
     from .precision import max_amps_in_msg
 
     if qureg.num_amps_total > max_amps_in_msg():

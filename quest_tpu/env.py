@@ -14,7 +14,6 @@ collectives ride ICI/DCN instead of MPI.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import os
 from typing import Optional, Sequence
 
@@ -26,29 +25,17 @@ from . import rng
 
 AMP_AXIS = "amps"
 
-# --- shard_map compat shim -------------------------------------------------
-# jax >= 0.6 exposes jax.shard_map (kwarg check_vma=); 0.4.x only has
-# jax.experimental.shard_map.shard_map (kwarg check_rep=).  Every module
-# imports shard_map from HERE so the whole package tracks one spelling.
-try:
-    from jax import shard_map as _shard_map_impl  # type: ignore[attr-defined]
-except ImportError:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters)
+# Every module imports shard_map from HERE so the whole package tracks one
+# spelling of its options.
+from jax import shard_map as _shard_map_impl  # noqa: E402
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """Version-portable shard_map: forwards ``check_vma`` under whichever
-    name the installed jax accepts (``check_vma`` new, ``check_rep`` old);
-    omitted -> the jax default."""
+    """jax.shard_map with ``check_vma`` forwarded only when given
+    (omitted -> the jax default)."""
     kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        else:
-            kwargs["check_rep"] = check_vma
+        kwargs["check_vma"] = check_vma
     return _shard_map_impl(f, **kwargs)
 
 
@@ -147,59 +134,50 @@ def compile_cache_stats() -> dict:
     return dict(_CACHE_STATS)
 
 
+def _checkout_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: the directory beside this package — a
+    fixed path, since the path is part of every cache key."""
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+
+
 def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache (opt out: QT_NO_COMPILE_CACHE=1;
-    relocate: QT_COMPILE_CACHE=<dir> — QT_COMPILE_CACHE_DIR kept as an
-    alias).  A traced-program framework re-pays compilation EVERY session
-    where the reference's CMake build compiles once — round-3 measured
-    22-47 s per 30q workload and 173-300 s for the config-4 noise block
-    per session (BASELINE.md), and bench_r05 shows up to 7.7 s compile_s
-    per bench config paid on every process start; the cache makes every
-    session after the first start warm.  Cache hits/misses are counted
-    (jax.monitoring listeners) and surfaced by getEnvironmentString.  No
-    reference analogue needed (VERDICT r3 item 5)."""
+    """Persistent XLA compilation cache.  Where JAX_COMPILATION_CACHE_DIR
+    is set (or jax_compilation_cache_dir configured before
+    createQuESTEnv), JAX uses that directory and nothing else is set
+    here.  Otherwise QT_COMPILE_CACHE=<dir> (alias QT_COMPILE_CACHE_DIR)
+    names one on any backend, and an accelerator backend defaults to
+    ``<checkout>/.jax_cache`` (_checkout_cache_dir); the CPU backend
+    stays uncached by default, because CPU executables embed the compile
+    host's microarchitecture.  QT_NO_COMPILE_CACHE=1 opts out.  Hits and
+    misses are counted (jax.monitoring listeners) and surfaced by
+    getEnvironmentString."""
     if _CACHE_WIRED[0] or os.environ.get("QT_NO_COMPILE_CACHE") == "1":
         return
     _CACHE_WIRED[0] = True
-    explicit_dir = (os.environ.get("QT_COMPILE_CACHE")
-                    or os.environ.get("QT_COMPILE_CACHE_DIR"))
-    try:
-        # respect a user-configured cache location (standard JAX env var
-        # or an explicit jax.config set before createQuESTEnv); inside
-        # the try so a JAX version lacking the config attribute skips the
-        # best-effort cache instead of breaking createQuESTEnv
-        user_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                    or jax.config.jax_compilation_cache_dir)
-        if user_dir:
-            _CACHE_STATS["dir"] = user_dir
-            _register_cache_listeners()
-            return
-        # CPU AOT cache entries embed the compile host's microarch
-        # features and can SIGILL on a different host (XLA warns on
-        # load); the compile cost being killed is the accelerator
-        # programs' anyway — default the cache on only off-CPU
-        # (QT_COMPILE_CACHE / QT_COMPILE_CACHE_DIR force it on anywhere)
-        if jax.default_backend() == "cpu" and explicit_dir is None:
-            return
-    # qlint: allow(broad-except): cache is best-effort — any config/backend probe failure (version-dependent attribute set) must skip the cache, never break createQuESTEnv
-    except Exception:  # pragma: no cover - cache is best-effort
+    user_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                or jax.config.jax_compilation_cache_dir)
+    if user_dir:
+        _CACHE_STATS["dir"] = user_dir
+        _register_cache_listeners()
         return
-    cache_dir = explicit_dir or os.path.join(
-        os.path.expanduser("~"), ".cache", "quest_tpu_xla")
+    cache_dir = (os.environ.get("QT_COMPILE_CACHE")
+                 or os.environ.get("QT_COMPILE_CACHE_DIR"))
+    if cache_dir is None:
+        if jax.default_backend() == "cpu":
+            return
+        cache_dir = _checkout_cache_dir()
     try:
         os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache EVERY compiled program: the per-pass chained executor's
-        # programs each compile in ~2 s or less, and re-tracing them per
-        # session is exactly the cost being killed — the default
-        # thresholds would skip them
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _CACHE_STATS["dir"] = cache_dir
-        _register_cache_listeners()
-    # qlint: allow(broad-except): cache is best-effort — mkdir/config failures (read-only FS, old JAX) degrade to uncached compiles rather than failing env creation
-    except Exception:  # pragma: no cover - cache is best-effort
-        pass
+    except OSError:  # read-only checkout: compile uncached
+        return
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # cache every compiled program: the per-pass kernels each compile
+    # in a second or two, under the default thresholds
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _CACHE_STATS["dir"] = cache_dir
+    _register_cache_listeners()
 
 
 def create_quest_env(
@@ -406,7 +384,7 @@ def get_environment_string(env: QuESTEnv) -> str:
 
     mk = _fused.megakernel_mode()
     mk_total = telemetry.counter_total("megakernel_dispatch_total")
-    if mk != "auto" or _fused.megakernel_planning() or mk_total:
+    if mk == "on" or mk_total:
         s += (f" Megakernel={mk}"
               f"({'on' if _fused.megakernel_planning() else 'off'})")
         mk_routes = ",".join(

@@ -237,8 +237,9 @@ def _perm_runs(seg):
     """Partition one gate segment into maximal runs of permutation-
     classified gates and interleaved dense runs, in stream order:
     ``[("perm" | "dense", [gates...]), ...]``.  Runs shorter than
-    _PERM_RUN_MIN are demoted to dense; with QT_PERM_FAST off everything
-    is one dense run (the A/B baseline)."""
+    _PERM_RUN_MIN are demoted to dense; with the permutation fast paths
+    off (C.perm_fast_enabled: QT_PERM_FAST, and always on the TPU)
+    everything is one dense run."""
     if not C.perm_fast_enabled():
         return [("dense", list(seg))]
     flags = [C.classify_permutation_gate(g.mat) is not None for g in seg]
@@ -809,6 +810,9 @@ def _plan_runner(nloc: int, program: tuple, mesh, precision: str = None,
         from .parallel import dist as PAR
 
         _ndev = PAR.amp_axis_size(mesh)
+        # the chunk policy of the mesh's own devices (a program compiled
+        # for a TPU mesh from a CPU process pipelines as on the chip)
+        _backend = PAR.mesh_platform(mesh)
 
     def _apply_part(part, amps, arrays, probs, ai, pi):
         if part[0] == "plan":
@@ -828,8 +832,9 @@ def _plan_runner(nloc: int, program: tuple, mesh, precision: str = None,
             # shard_map body
             from .parallel import dist as PAR
             amps = PAR._remap_in_shard(
-                amps.reshape(2, -1), part[1], nloc, _ndev
-            ).reshape(amps.shape)
+                amps, part[1], nloc, _ndev,
+                PAR.remap_chunk_plan(nloc, amps.dtype.itemsize,
+                                     backend=_backend))
         elif part[0] == "chansweep":
             entries = part[1]
             from .ops import fused as _fusedmod
@@ -1000,8 +1005,8 @@ def capture_pair_channel(qureg, kind: str, target: int, prob) -> bool:
     call order with the gate segments, so a whole noise layer is one
     dispatch.  Deliberately NOT a superoperator fold (capture_raw): these
     channels' superoperators have operator-Schmidt rank 4 across
-    (t, t+n), and a rank-4 window pass per channel measured slower than
-    the elementwise kernel (BASELINE.md round-3)."""
+    (t, t+n): a rank-4 window pass per channel does four times the
+    matmul work of the elementwise kernel."""
     sh = qureg.num_qubits_represented
     bits = (target, target + sh)
     if not _capturable(qureg, bits):

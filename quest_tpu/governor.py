@@ -72,18 +72,46 @@ _BUDGET_ENV = "QT_HBM_BUDGET_BYTES"
 _POLICIES = ("off", "degrade", "strict")
 
 # --- live-copy multiplier model (docs/design.md §22) ---------------------
-# A gate/channel part holds the donated output next to the input for the
-# duration of one pass; a window remap additionally materializes its
-# exchange transient: the WHOLE shard when monolithic (PR-3's pinned
-# 2.0-shard peak), at most two in-flight chunks when pipelined over C
-# chunks (the pinned 1.25-shard peak at C=8 -> extra = 2/C).
+# Parts are priced by what the TPU compiler allocates for them.  The
+# Pallas window / cluster / swap-cluster / sigma-swap passes, the
+# canonical diagonal op and the channel sweep alias their donated input:
+# no extra state copy (each compiled for a described v5e at 30 qubits
+# with no state-sized temporary, tests/test_chip_compile.py).  Any other
+# part — an XLA permutation, general-matrix or pair-channel op, or a
+# megawin group, which the v5e compiler refuses — writes one second
+# state.  A window remap additionally materializes its exchange
+# transient.  On a flat shard: the WHOLE shard when monolithic (2.0
+# shards), at most two in-flight chunks when pipelined over C chunks
+# (1 + 2/C).  On a canonical shard the swap runs in place
+# (dist._swap_halves_canonical) and holds five half-shard chunks: two
+# sends in flight, their receive buffers and the combined chunk (2.5/C;
+# the 32-qubit drain on a described four-chip v5e, C = 8, compiles to
+# temp 2.61 GiB beside its 8 GiB shard, tests/test_chip_compile.py).
 GATE_PART_EXTRA = 1.0
+IN_PLACE_OPS = frozenset({"winfused", "fused", "swapfused", "sigma_swap",
+                          "diag"})
 
 
-def remap_part_extra(chunks: int) -> float:
+def part_extra(part, chunks: int, canonical: bool) -> float:
+    """Extra live state copies one program part holds at its peak
+    (``canonical``: the register holds canonical-shape shards)."""
+    kind = part[0]
+    if kind == "remap":
+        return remap_part_extra(chunks, canonical)
+    if kind == "chansweep":
+        return 0.0
+    if kind == "plan" and all(sk[0] in IN_PLACE_OPS for sk in part[1]):
+        return 0.0
+    return GATE_PART_EXTRA
+
+
+def remap_part_extra(chunks: int, canonical: bool) -> float:
     """Extra live shard-copies of one ("remap", sigma) part at chunk
-    count ``chunks`` — 2.0 monolithic, 1 + 2/C pipelined."""
+    count ``chunks`` — 2.5/C in place on a canonical shard; on a flat
+    one 2.0 monolithic, 1 + 2/C pipelined."""
     c = max(int(chunks), 1)
+    if canonical:
+        return 2.5 / c
     return 2.0 if c <= 1 else 1.0 + 2.0 / c
 
 
@@ -496,24 +524,30 @@ def _arrays_bytes(arrays) -> int:
     return int(sum(int(getattr(a, "nbytes", 0) or 0) for a in arrays))
 
 
-def _resolved_chunks(nloc: int, itemsize: int, nsh: int) -> int:
+def _resolved_chunks(qureg, nloc: int, nsh: int) -> int:
     """Full-shard chunk count the remap parts will resolve under the
-    LIVE chunk policy (env override / governor override / heuristic)."""
+    LIVE chunk policy (env override / governor override / heuristic) on
+    the register's own devices."""
     if not nsh:
         return 1
     from .parallel import dist as PAR
 
-    return int(PAR.remap_chunk_plan(nloc, itemsize)[1])
+    return int(PAR.remap_chunk_plan(
+        nloc, np.dtype(qureg.dtype).itemsize,
+        backend=PAR.mesh_platform(qureg.env.mesh))[1])
 
 
-def _program_peak(program, state: int, arrays_b: int, chunks: int) -> int:
+def _canonical(qureg) -> bool:
+    shape = qureg.device_shape()
+    return shape is not None and len(shape) == 4
+
+
+def _program_peak(program, state: int, arrays_b: int, chunks: int,
+                  canonical: bool) -> int:
     """Predicted per-device peak of dispatching ``program`` as ONE
     group: state x (1 + max part extra) + pass-array bytes."""
-    extra = 0.0
-    for part in program:
-        pe = (remap_part_extra(chunks) if part[0] == "remap"
-              else GATE_PART_EXTRA)
-        extra = max(extra, pe)
+    extra = max((part_extra(part, chunks, canonical) for part in program),
+                default=0.0)
     return int(state * (1.0 + extra)) + int(arrays_b)
 
 
@@ -525,10 +559,9 @@ def predict_drain(qureg, program, arrays, *, nloc: int, nsh: int,
     itemsize = np.dtype(qureg.dtype).itemsize
     state = register_bytes_per_device(qureg)
     arrays_b = _arrays_bytes(arrays)
-    c = chunks if chunks is not None else _resolved_chunks(
-        nloc, itemsize, nsh)
-    peak = (_program_peak(program, state, arrays_b, c) if program
-            else state)
+    c = chunks if chunks is not None else _resolved_chunks(qureg, nloc, nsh)
+    peak = (_program_peak(program, state, arrays_b, c, _canonical(qureg))
+            if program else state)
     other = resident_bytes(exclude=qureg)
     b = budget_bytes()
     # per-interconnect-tier exchange bytes of the drain's remap parts —
@@ -571,7 +604,7 @@ def predict_drain(qureg, program, arrays, *, nloc: int, nsh: int,
 
 
 def _split_program(program, arrays, state: int, other: int, b: int,
-                   chunks: int):
+                   chunks: int, canonical: bool):
     """Rung 2: greedily pack program parts into contiguous dispatch
     groups so each group's peak (state x (1+max extra) + its own pass
     arrays) fits the remaining budget.  Part boundaries already carry an
@@ -592,7 +625,7 @@ def _split_program(program, arrays, state: int, other: int, b: int,
         start = sum(len(g) for g in groups)
         trial_b = sum(sizes[start:start + len(trial)])
         if cur and other + _program_peak(
-                trial, state, trial_b, chunks) > b:
+                trial, state, trial_b, chunks, canonical) > b:
             groups.append(tuple(cur))
             cur = [part]
         else:
@@ -606,7 +639,7 @@ def _split_program(program, arrays, state: int, other: int, b: int,
     for g in groups:
         gb = sum(sizes[start:start + len(g)])
         start += len(g)
-        if other + _program_peak(g, state, gb, chunks) > b:
+        if other + _program_peak(g, state, gb, chunks, canonical) > b:
             return None
     return tuple(groups)
 
@@ -628,12 +661,12 @@ def govern_drain(qureg, program, arrays, *, nloc: int, nsh: int):
     from .parallel import dist as PAR
 
     b = budget_bytes()
-    itemsize = np.dtype(qureg.dtype).itemsize
     state = register_bytes_per_device(qureg)
     arrays_b = _arrays_bytes(arrays)
     other = resident_bytes(exclude=qureg)
-    c0 = _resolved_chunks(nloc, itemsize, nsh)
-    need = _program_peak(program, state, arrays_b, c0)
+    canonical = _canonical(qureg)
+    c0 = _resolved_chunks(qureg, nloc, nsh)
+    need = _program_peak(program, state, arrays_b, c0, canonical)
     if other + need <= b:
         _record_usage(other + need)
         return None
@@ -653,7 +686,8 @@ def govern_drain(qureg, program, arrays, *, nloc: int, nsh: int):
         t = max(c0, 1)
         while t < cap:
             t *= 2
-            if other + _program_peak(program, state, arrays_b, t) <= b:
+            if other + _program_peak(program, state, arrays_b, t,
+                                     canonical) <= b:
                 pick = t
                 break
         if pick is None and cap > c0:
@@ -664,17 +698,18 @@ def govern_drain(qureg, program, arrays, *, nloc: int, nsh: int):
             applied.append(("chunks",
                             f"exchange chunks {c0} -> {c} to shrink "
                             "remap transients"))
-            need = _program_peak(program, state, arrays_b, c)
+            need = _program_peak(program, state, arrays_b, c, canonical)
 
     # rung 2: split the oversized window into smaller dispatch groups
     groups = None
     if other + need > b:
-        groups = _split_program(program, arrays, state, other, b, c)
+        groups = _split_program(program, arrays, state, other, b, c,
+                                canonical)
         if groups is not None:
             applied.append(("split",
                             f"drain split into {len(groups)} dispatch "
                             "groups"))
-            need = _max_group_peak(groups, arrays, state, c)
+            need = _max_group_peak(groups, arrays, state, c, canonical)
 
     # rung 3: spill idle registers (LRU) to free co-resident bytes
     if other + need > b:
@@ -697,7 +732,8 @@ def govern_drain(qureg, program, arrays, *, nloc: int, nsh: int):
     return {"groups": groups, "chunks": c if c != c0 else None}
 
 
-def _max_group_peak(groups, arrays, state: int, chunks: int) -> int:
+def _max_group_peak(groups, arrays, state: int, chunks: int,
+                    canonical: bool) -> int:
     """Exact max per-group peak: walks the pass-array offsets group by
     group (the same accounting fusion's dispatch loop uses)."""
     ai = 0
@@ -706,7 +742,7 @@ def _max_group_peak(groups, arrays, state: int, chunks: int) -> int:
         na = sum(p[2] if p[0] == "plan" else 0 for p in g)
         gb = _arrays_bytes(arrays[ai:ai + na])
         ai += na
-        worst = max(worst, _program_peak(g, state, gb, chunks))
+        worst = max(worst, _program_peak(g, state, gb, chunks, canonical))
     return worst
 
 

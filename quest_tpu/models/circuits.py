@@ -232,5 +232,5 @@ def prob_top_zero_canonical(a):
 @jax.jit
 def amp00_canonical(a):
     """Layout-preserving scalar sync on the canonical view (a gather-style
-    a[0,0,0,0] makes XLA relayout the whole state)."""
+    a[0,0,0,0] makes XLA re-layout the whole state)."""
     return jnp.sum(a[:1, :1, :1, :1])

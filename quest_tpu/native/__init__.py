@@ -1,7 +1,10 @@
 """ctypes binding + on-demand build of the native C++ circuit scheduler.
 
-The library (scheduler.cc) is compiled once with g++ into _qts.so next to
-this file; if the toolchain is unavailable the import degrades gracefully
+The library (scheduler.cc) is compiled with g++ into _qts.so next to this
+file, and rebuilt whenever the source's content hash differs from the one
+recorded beside the library (_qts.so.sha256) — a library built from other
+source is never loaded; if the toolchain is unavailable the import degrades
+gracefully
 and circuit.py falls back to its Python planner (same algorithm — the
 native path exists for million-gate streams where per-gate Python
 bookkeeping dominates).  Disable with QT_NATIVE=0.
@@ -10,6 +13,7 @@ bookkeeping dominates).  Disable with QT_NATIVE=0.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,13 +24,30 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "scheduler.cc")
 _LIB = os.path.join(_DIR, "_qts.so")
+_LIB_HASH = _LIB + ".sha256"
 
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
 
 
-def _build() -> bool:
+def _source_hash() -> Optional[str]:
+    try:
+        with open(_SRC, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()
+    except OSError:
+        return None
+
+
+def _built_hash() -> Optional[str]:
+    try:
+        with open(_LIB_HASH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _build(digest: str) -> bool:
     tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
         subprocess.run(
@@ -34,6 +55,9 @@ def _build() -> bool:
             check=True, capture_output=True, timeout=120,
         )
         os.replace(tmp, _LIB)  # atomic: concurrent readers never see a torn .so
+        with open(tmp, "w") as f:
+            f.write(digest)
+        os.replace(tmp, _LIB_HASH)
         return True
     except (subprocess.SubprocessError, OSError):
         try:
@@ -53,11 +77,13 @@ def get_lib():
             return _lib
         if _build_failed:
             return None
-        if not os.path.exists(_LIB) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_LIB)
-        ):
-            if not _build():
+        digest = _source_hash()
+        if digest is None and not os.path.exists(_LIB):
+            _build_failed = True
+            return None
+        if digest is not None and (not os.path.exists(_LIB)
+                                   or _built_hash() != digest):
+            if not _build(digest):
                 _build_failed = True
                 return None
         try:

@@ -137,11 +137,16 @@ def calc_total_prob_density(amps, *, num_qubits: int):
 def calc_prob_of_outcome_statevec(amps, *, num_qubits: int, target: int,
                                   outcome: int, quad: bool = False):
     """(statevec_calcProbOfOutcome, QuEST_cpu.c:3418-3508)."""
-    from .kernels import bit_indicator_2d
+    from .kernels import bit_indicator_2d, bit_indicator_canonical
 
     n = num_qubits
-    ind = bit_indicator_2d(n, ((target, outcome),), amps.dtype)
-    view = amps.reshape(2, ind.shape[0], ind.shape[1])
+    if amps.ndim == 4:
+        # the canonical view reduces in its own layout (no re-layout copy)
+        ind = bit_indicator_canonical(n, ((target, outcome),), amps.dtype)
+        view = amps
+    else:
+        ind = bit_indicator_2d(n, ((target, outcome),), amps.dtype)
+        view = amps.reshape(2, ind.shape[0], ind.shape[1])
     if quad:
         return quad_sum2(view[0] * view[0] * ind, view[1] * view[1] * ind)
     return jnp.sum(cplx.abs2(view) * ind)

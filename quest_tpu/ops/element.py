@@ -1,9 +1,9 @@
 """Layout-safe element access: jitted slice kernels on the canonical view.
 
 getAmp-class reads and setAmps-class writes must never trigger a
-full-state relayout: an eager ``amps[:, index]`` on a canonically-tiled
+full-state re-layout: an eager ``amps[:, index]`` on a canonically-tiled
 28q+ state makes XLA first copy the WHOLE state into the default flat
-layout — the round-3 30q relayout-OOM diagnosis (BASELINE.md) — where
+layout (a second state, which does not fit beside a 30q one) — where
 the reference's getAmp is an O(1) chunk read (QuEST.h:1987,
 QuEST_cpu_local.c:225-233).
 
@@ -65,17 +65,34 @@ def _as_canonical(amps):
     return amps.reshape(2, -1, DIM, DIM)
 
 
+def _owner_shard(amps, block: int):
+    """(shard data, local block) of the device holding canonical block
+    ``block`` of a state sharded on its block axis.  A dynamic slice of
+    the sharded array would make XLA gather the whole state to every
+    device (32 GiB at 32 qubits over four chips); the owner's own shard
+    is read on its device instead."""
+    for shard in amps.addressable_shards:
+        rows = shard.index[1]
+        start = rows.start or 0
+        stop = amps.shape[1] if rows.stop is None else rows.stop
+        if start <= block < stop:
+            return shard.data, block - start
+    raise ValueError(f"block {block} is on no device of this process")
+
+
 def get_amp_pair(amps, index: int):
-    """(re, im) device pair of amplitude ``index`` without any relayout.
+    """(re, im) device pair of amplitude ``index`` without any re-layout.
     Accepts the flat (2, 2^n) register form or the canonical 4-d view the
     chained big-state executor keeps (circuit.canonical_view)."""
     if amps.ndim != 4:
         if amps.shape[1] < BLK:
             return _get_pair_flat(amps, index)
         amps = _as_canonical(amps)
-    return _get_pair_canonical(
-        amps, index >> BLK_BITS, (index >> 7) & (DIM - 1),
-        index & (DIM - 1))
+    block = index >> BLK_BITS
+    if isinstance(amps, jax.Array) and len(amps.sharding.device_set) > 1:
+        amps, block = _owner_shard(amps, block)
+    return _get_pair_canonical(amps, block, (index >> 7) & (DIM - 1),
+                               index & (DIM - 1))
 
 
 def get_block_host(amps, b: int) -> np.ndarray:
@@ -94,7 +111,7 @@ def set_amp_range(amps, start: int, vals: np.ndarray):
     ``vals`` (2, m); returns the updated array in the SAME view/layout.
     Canonical states update tile-aligned whole blocks in one
     dynamic_update_slice plus read-modify-write edge tiles — never a
-    full-state relayout (the reference's setAmps writes into the local
+    full-state re-layout (the reference's setAmps writes into the local
     chunk in place, QuEST_cpu.c setAmps path)."""
     m = int(vals.shape[1])
     if m == 0:

@@ -592,6 +592,83 @@ def apply_cluster_stack(amps, mats_a, mats_b, *, precision=None, **kw):
 
 
 # ---------------------------------------------------------------------------
+# Diagonal gate on the canonical view: the one-pass op for a diagonal gate
+# no window pass covers (circuit._fallback_op).  An XLA complex multiply
+# cannot write in place (each output channel reads both input channels),
+# so at 30 qubits it needs a second state; this kernel rewrites each
+# block in VMEM instead.
+# ---------------------------------------------------------------------------
+
+
+def _diag_kernel(x_ref, t_ref, o_ref):
+    x = x_ref[...]                       # (2, R, 128, 128)
+    fr = t_ref[0, 0]                     # (128, 128) factor for this block
+    fi = t_ref[0, 1]
+    o_ref[...] = jnp.stack([x[0] * fr - x[1] * fi, x[0] * fi + x[1] * fr])
+
+
+@partial(jax.jit, static_argnames=("num_qubits", "targets", "interpret"),
+         donate_argnums=0)
+def apply_diagonal_canonical(amps, diag, *, num_qubits: int,
+                             targets: tuple, interpret: bool | None = None):
+    """Multiply the state by ``diag[bits(targets)]`` ((2, 2^k) SoA
+    diagonal), n >= 14, in ONE in-place pass over the canonical
+    (2, 2^(n-14), 128, 128) view.  The in-block factor for each
+    combination of the targets at or above bit 14 is a (2, 128, 128)
+    table row; a grid step covers rows that share those bits, and its
+    index map picks the row."""
+    n = num_qubits
+    in_shape = amps.shape
+    interpret = _resolve_interpret(interpret, amps)
+    nb = 1 << (n - CLUSTER_QUBITS)
+    diag = jnp.asarray(diag, amps.dtype)
+    blk = [(j, t - CLUSTER_QUBITS) for j, t in enumerate(targets)
+           if t >= CLUSTER_QUBITS]
+    ri = jax.lax.broadcasted_iota(jnp.int32, (CLUSTER_DIM, CLUSTER_DIM), 0)
+    li = jax.lax.broadcasted_iota(jnp.int32, (CLUSTER_DIM, CLUSTER_DIM), 1)
+    code = jnp.zeros((CLUSTER_DIM, CLUSTER_DIM), jnp.int32)
+    for j, t in enumerate(targets):
+        if t < LANE_QUBITS:
+            code = code | (((li >> t) & 1) << j)
+        elif t < CLUSTER_QUBITS:
+            code = code | (((ri >> (t - LANE_QUBITS)) & 1) << j)
+    rows = []
+    for g in range(1 << len(blk)):
+        hi = 0
+        for i, (j, _b) in enumerate(blk):
+            hi |= ((g >> i) & 1) << j
+        rows.append(jnp.stack([jnp.take(diag[0], code | hi),
+                               jnp.take(diag[1], code | hi)]))
+    table = jnp.stack(rows)              # (2^|blk|, 2, 128, 128)
+    low_bit = min((b for _j, b in blk), default=n - CLUSTER_QUBITS)
+    r = min(8, 1 << low_bit, nb)
+
+    def row_of(i):
+        g = 0
+        for k, (_j, b) in enumerate(blk):
+            g = g | ((((i * r) >> b) & 1) << k)
+        return g
+
+    view = amps.reshape(2, nb, CLUSTER_DIM, CLUSTER_DIM)
+    out = pl.pallas_call(
+        _diag_kernel,
+        grid=(nb // r,),
+        in_specs=[
+            pl.BlockSpec((2, r, CLUSTER_DIM, CLUSTER_DIM),
+                         lambda i: (0, i, 0, 0)),
+            pl.BlockSpec((1, 2, CLUSTER_DIM, CLUSTER_DIM),
+                         lambda i: (row_of(i), 0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((2, r, CLUSTER_DIM, CLUSTER_DIM),
+                               lambda i: (0, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
+        input_output_aliases={0: 0},
+        interpret=interpret,
+    )(view, table)
+    return out.reshape(in_shape)
+
+
+# ---------------------------------------------------------------------------
 # Window megakernel (docs/design.md §29): a RUN of window passes in ONE
 # pallas_call — one HBM read + one HBM write for the whole run instead of
 # one round-trip per pass.  Eligible passes have window offset k <= 7 + g
@@ -603,95 +680,25 @@ def apply_cluster_stack(amps, mats_a, mats_b, *, precision=None, **kw):
 
 
 def megakernel_mode() -> str:
-    """QT_MEGAKERNEL knob: "off" (never group), "on" (force, including
-    interpret mode — the CPU test/bench arm), "auto" (default: group and
-    execute fused only on a real TPU with a Mosaic-supported dtype)."""
+    """QT_MEGAKERNEL knob: "on" forms megawin groups on every backend
+    (interpret mode off the TPU — the CPU test/bench arm); anything else
+    is "off", the default.  On a v5e the Mosaic compiler refuses
+    the megakernel for a k=7 single-side member (a layout with an
+    implicit dimension) and overflows scoped VMEM for rank-2 single-side
+    members at 8 rows, both at row caps megawin_row_cap admits
+    (tests/test_chip_compile.py compiles the kernel for a described
+    v5e), so the default plans per-pass window kernels only."""
     import os
 
-    raw = os.environ.get("QT_MEGAKERNEL", "auto").strip().lower()
-    if raw in ("off", "0", "false", "no"):
-        return "off"
-    if raw in ("on", "1", "true", "yes"):
-        return "on"
-    return "auto"
-
-
-# one-shot Mosaic lowering probe, same contract as paulis._PALLAS_OK: a
-# failed compile downgrades every megawin group to the per-pass route for
-# the rest of the process and records itself in the env report.
-_MEGA_OK: dict = {}
-
-
-def _probe_megakernel_lowering() -> None:
-    """Compile (don't run) a representative two-pass megakernel at the
-    largest row grouping the budget rule admits (G = 8, k = 10): Mosaic
-    VMEM overflows and lowering failures both surface at compile time."""
-    n = 17
-    amps = jax.ShapeDtypeStruct((2, 1 << n), jnp.float32)
-    m = jax.ShapeDtypeStruct((1, 2, CLUSTER_DIM, CLUSTER_DIM), jnp.float32)
-    spec = ((LANE_QUBITS, 1, True, True, False),
-            (LANE_QUBITS + 3, 1, False, True, False))
-
-    def f(x, a1, b1, a2, b2):
-        return _apply_megawin_jit(x, a1, b1, a2, b2, num_qubits=n,
-                                  spec=spec, interpret=False)
-
-    jax.jit(f).lower(amps, m, m, m, m).compile()
-
-
-def megakernel_lowering_ok() -> bool:
-    """True when the window megakernel compiles on this backend; cached
-    per process.  On failure, warn once, record the downgrade in the env
-    report, and decompose megawin groups to per-pass dispatches."""
-    hit = _MEGA_OK.get("ok")
-    if hit is not None:
-        return hit
-    try:
-        _probe_megakernel_lowering()
-        ok = True
-    # qlint: allow(broad-except): Mosaic failures span XlaRuntimeError/NotImplementedError/TypeError depending on backend and version; every one means "use the per-pass route" and is recorded in the degradation registry
-    except Exception as e:
-        from .. import resilience
-
-        resilience.record_degradation(
-            "pallas-window-megakernel",
-            "window megakernel failed to compile; megawin groups decompose "
-            f"to per-pass dispatches ({type(e).__name__}: {e})")
-        ok = False
-    _MEGA_OK["ok"] = ok
-    return ok
+    raw = os.environ.get("QT_MEGAKERNEL", "off").strip().lower()
+    return "on" if raw in ("on", "1", "true", "yes") else "off"
 
 
 def megakernel_planning() -> bool:
-    """Whether the planner should FORM megawin groups at all.  "auto"
-    groups only when a real TPU backs the process (the interpret-mode
-    expansion of a fused group is *larger* XLA than per-pass dispatch, so
-    CPU keeps the old plans bit-for-bit); QT_MEGAKERNEL=on forces grouping
-    everywhere — the knob tests and the CPU A/B bench arm use."""
-    mode = megakernel_mode()
-    if mode == "off":
-        return False
-    if mode == "on":
-        return True
-    return not _interpret_default()
-
-
-def megakernel_executable(dtype=None) -> bool:
-    """Whether a megawin group should EXECUTE through the fused kernel.
-    The fallback ladder below "auto" (each rung decomposes the group to
-    the existing per-pass route, bit-identically): non-TPU backend ->
-    interpret mode is slower fused than split; f64 state -> Mosaic can't
-    lower the dots; Mosaic compile failure -> degradation registry."""
-    mode = megakernel_mode()
-    if mode == "off":
-        return False
-    if mode == "on":
-        return True
-    if _interpret_default():
-        return False
-    if dtype is not None and jnp.dtype(dtype) == jnp.float64:
-        return False
-    return megakernel_lowering_ok()
+    """Whether the planner forms megawin groups: only under
+    QT_MEGAKERNEL=on.  A planned group always executes fused — on the
+    TPU the kernel compiles or the run raises."""
+    return megakernel_mode() == "on"
 
 
 def megawin_row_cap(rank: int, num_qubits: int) -> int:

@@ -32,7 +32,7 @@ full-state scan.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Tuple
 
 import jax
@@ -241,6 +241,32 @@ def bit_indicator_2d(n: int, bit_states, dtype):
         else:
             mhi = mhi & (bits_mod.bits_of(ihi, b - lo) == int(s))
     return (mhi[:, None] & mlo[None, :]).astype(dtype)
+
+
+def bit_canonical(n: int, q: int):
+    """Per-amplitude value of qubit q's bit, broadcastable over the
+    canonical (2^(n-14), 128, 128) view (block, sublane, lane axes)."""
+    from ..utils import bits as bits_mod
+
+    if q < _LANE_BITS:
+        axis, size, b = 2, 128, q
+    elif q < _BIG_N:
+        axis, size, b = 1, 128, q - _LANE_BITS
+    else:
+        axis, size, b = 0, 1 << (n - _BIG_N), q - _BIG_N
+    shape = [1, 1, 1]
+    shape[axis] = size
+    return bits_mod.bits_of(jax.lax.broadcasted_iota(jnp.int32, shape, axis),
+                            b)
+
+
+def bit_indicator_canonical(n: int, bit_states, dtype):
+    """bit_indicator_2d over the canonical (2^(n-14), 128, 128) view."""
+    ind = None
+    for b, s in bit_states:
+        eq = bit_canonical(n, b) == int(s)
+        ind = eq if ind is None else (ind & eq)
+    return ind.astype(dtype)
 
 
 def _flip_bits_flat(amps, n: int, targets):
@@ -810,6 +836,37 @@ def apply_index_permutation(
 # ---------------------------------------------------------------------------
 # State initialisation (reference QuEST_cpu.c:1453-1729)
 # ---------------------------------------------------------------------------
+
+
+def _filled(shape, dtype, kind: str, x):
+    """A ``kind`` state of ``shape``: "basis" puts 1 at flat amplitude
+    index ``x`` (traced), "plus" fills the real channel with ``x``,
+    "blank" is all zero.  4-d shapes are the canonical view
+    (qureg.device_amps_shape), addressed (block, sublane, lane)."""
+    z = jnp.zeros(shape, dtype)
+    if kind == "basis":
+        if len(shape) == 4:
+            idx = (0, x >> 14, (x >> _LANE_BITS) & 127, x & 127)
+        else:
+            idx = (0, x)
+        return z.at[idx].set(1)
+    if kind == "plus":
+        return z.at[0].set(jnp.asarray(x, dtype))
+    return z
+
+
+@lru_cache(maxsize=None)
+def _fill_fn(shape, dtype, kind: str, sharding):
+    return jax.jit(lambda x: _filled(shape, dtype, kind, x),
+                   out_shardings=sharding)
+
+
+def fill_state(shape, dtype, sharding, kind: str, x=0):
+    """Build a "basis" / "plus" / "blank" state (see _filled) directly on
+    the device in ``shape`` and ``sharding`` — no host array, no flat
+    intermediate."""
+    return _fill_fn(tuple(int(d) for d in shape), np.dtype(dtype), kind,
+                    sharding)(x)
 
 
 def init_blank_state(num_amps: int, dtype):

@@ -304,12 +304,10 @@ def make_expec_term_value(dt, n: int, layer, signed_norm):
 #                                            - i sin(th/2) (P psi)
 # with (P psi)[i] = (-i)^{#Y} * (-1)^{parity(i & zm)} * psi[i ^ fm]
 # (fm = X|Y bits, zm = Z|Y bits, P^2 = I).  ONE split-axis gather + one
-# fused elementwise combine per term — measured ~2.2 ms/term at 24q vs
-# ~17 ms/term for the rotate-layer -> parity-phase -> unrotate-layer
-# body it replaces (scripts/probes/probe_trotter_direct_result.json:
-# direct_rowcol 0.0345 s vs window_scan 0.277 s for 16 terms, same
-# session; a flat 2^24 gather is ~160x slower — the (hi, lo) row/lane
-# split is what makes the permutation DMA-friendly).  The reference's
+# fused elementwise combine per term, in place of a rotate-layer ->
+# parity-phase -> unrotate-layer body (three passes); the (hi, lo)
+# row/lane split keeps the permutation's gather DMA-friendly.  The
+# reference's
 # multiRotatePauli instead conjugates by basis rotations
 # (QuEST_common.c:424-462).
 # ---------------------------------------------------------------------------
@@ -413,73 +411,34 @@ def _direct_rotation(amps, codes, ang, nq: int, offset: int, n: int,
 
 # ---------------------------------------------------------------------------
 # Pallas fused direct rotation: the whole term in ONE HBM pass per block
-# (scripts/probes/probe_flip_pallas.py measured 2.3x over the take-take
-# gather at 24q, bit-identical).  The XOR permutation decomposes as
+# (bit-identical to the take-take gather).  The XOR permutation
+# decomposes as
 #   - block-level row XOR: the flip input's BlockSpec index_map reads
 #     block (i ^ (fm_row >> 8)) — pure DMA redirection;
 #   - in-block row XOR (8 bits) and lane XOR (7 bits): dynamically built
 #     0/1 permutation matmuls (256x256 and 128x128) on the MXU — Mosaic
 #     has no rev lowering, and at HIGHEST precision a permutation matmul
 #     is exact;
-# parity signs factor as s_row (x) s_lane, built OUTSIDE the kernel.
+# parity signs factor as s_row (x) s_lane: the lane factor is a (1, 128)
+# input, the row factor is folded from the row iota in the kernel (a
+# (rows, 1) input would tile-pad to 128 lanes — half a state at 30 bits).
 # ---------------------------------------------------------------------------
 
 _PL_BR = 256            # rows per block (n >= _PL_MIN_N so R >= _PL_BR)
 _PL_MIN_N = 15
 
-# one-shot Pallas lowering probe result (None = not yet probed).  A
-# failed probe downgrades the direct-rotation/expectation path to the
-# XLA gather form for the rest of the process — graceful degradation
-# instead of a trace-time crash on a Mosaic/driver regression — and
-# records itself in the env report (resilience.record_degradation).
-_PALLAS_OK: dict = {}
-
-
-def _probe_pallas_lowering() -> None:
-    """Lower (don't run) a minimal rotation-kernel pallas_call at the
-    smallest routable size; raises on any Mosaic/lowering failure."""
-    probe_n = _PL_MIN_N
-    amps = jax.ShapeDtypeStruct((2, 1 << probe_n), jnp.float32)
-    codes = jax.ShapeDtypeStruct((probe_n,), jnp.int32)
-    ang = jax.ShapeDtypeStruct((), jnp.float32)
-
-    def f(a, c, t):
-        return _direct_rotation_pallas(a, c, t, probe_n, 0, probe_n,
-                                       conj=False)
-
-    # compile, not just lower: Mosaic failures surface at compile time
-    jax.jit(f).lower(amps, codes, ang).compile()
-
-
-def pallas_lowering_ok() -> bool:
-    """True when the fused Pallas term kernels lower on this backend;
-    cached per process.  On failure, warn once, record the downgrade in
-    the env report, and route through the XLA gather path instead."""
-    hit = _PALLAS_OK.get("ok")
-    if hit is not None:
-        return hit
-    try:
-        _probe_pallas_lowering()
-        ok = True
-    # qlint: allow(broad-except): Pallas lowering failures span XlaRuntimeError/NotImplementedError/TypeError depending on backend and version; every one of them means "use the XLA gather path" and is recorded as a degradation
-    except Exception as e:
-        from .. import resilience
-
-        resilience.record_degradation(
-            "pallas-direct-rotation",
-            "fused Pallas term kernel failed to lower; falling back to "
-            f"the XLA gather path ({type(e).__name__}: {e})")
-        ok = False
-    _PALLAS_OK["ok"] = ok
-    return ok
-
 
 def _pl_routable(amps, n: int) -> bool:
+    """The fused Pallas term kernels serve f32 states of 15..32 bits on
+    the TPU; there a kernel compiles or the run raises
+    (tests/test_chip_compile.py compiles them for a described v5e)."""
+    from . import fused as _fused
+
     return (_PL_MIN_N <= n <= 32 and amps.dtype == jnp.float32
-            and jax.default_backend() == "tpu" and pallas_lowering_ok())
+            and not _fused._interpret_default())
 
 
-def _pl_flip_signed(meta, fvals, x_ref, f_ref, srow_ref, slane_ref):
+def _pl_flip_signed(meta, fvals, x_ref, f_ref, slane_ref):
     """Shared kernel-body algebra: load the two blocks, apply the
     in-block row XOR and lane XOR as exact permutation matmuls, and
     return (x, pr, pi) with the parity sign and (-i)^{#Y} factor folded
@@ -506,8 +465,13 @@ def _pl_flip_signed(meta, fvals, x_ref, f_ref, srow_ref, slane_ref):
     pv = jnp.dot(f.reshape(2 * _PL_BR, 128), perm,
                  preferred_element_type=x.dtype,
                  precision=hi).reshape(2, _PL_BR, 128)
-    s = (srow_ref[...][:, 0][None, :, None]
-         * slane_ref[...][0][None, None, :])[0]
+    import jax.experimental.pallas as pl
+
+    rows = (pl.program_id(0) * _PL_BR
+            + lax.broadcasted_iota(jnp.int32, (_PL_BR, 128), 0)) & meta[3]
+    for sh in (16, 8, 4, 2, 1):
+        rows = rows ^ (rows >> sh)
+    s = (1 - 2 * (rows & 1)).astype(x.dtype) * slane_ref[...]
     c_re = fvals[0, 2]
     c_im = fvals[0, 3]
     pr = s * (c_re * pv[0] - c_im * pv[1])
@@ -515,18 +479,15 @@ def _pl_flip_signed(meta, fvals, x_ref, f_ref, srow_ref, slane_ref):
     return x, pr, pi
 
 
-def _pl_rotation_kernel(meta, fvals, x_ref, f_ref, srow_ref, slane_ref,
-                        out_ref):
-    x, pr, pi = _pl_flip_signed(meta, fvals, x_ref, f_ref, srow_ref,
-                                slane_ref)
+def _pl_rotation_kernel(meta, fvals, x_ref, f_ref, slane_ref, out_ref):
+    x, pr, pi = _pl_flip_signed(meta, fvals, x_ref, f_ref, slane_ref)
     co = fvals[0, 0]
     si = fvals[0, 1]
     out_ref[0, :, :] = co * x[0] + si * pi
     out_ref[1, :, :] = co * x[1] - si * pr
 
 
-def _pl_expec_kernel(meta, fvals, x_ref, f_ref, srow_ref, slane_ref,
-                     out_ref):
+def _pl_expec_kernel(meta, fvals, x_ref, f_ref, slane_ref, out_ref):
     """Per-term expectation contribution Re <x| P |x>: flip (same
     permutation algebra as the rotation kernel) + sign + product-reduce,
     one HBM pass — emitting ONE PARTIAL PER GRID BLOCK.  The (G,)
@@ -535,15 +496,17 @@ def _pl_expec_kernel(meta, fvals, x_ref, f_ref, srow_ref, slane_ref,
     rounding error grow linearly in the block count and loses
     cross-block cancellation exactly where terms with opposing signs
     should cancel (ADVICE r5)."""
-    x, pr, pi = _pl_flip_signed(meta, fvals, x_ref, f_ref, srow_ref,
-                                slane_ref)
-    out_ref[...] = jnp.sum(x[0] * pr + x[1] * pi).reshape(1, 1)
+    x, pr, pi = _pl_flip_signed(meta, fvals, x_ref, f_ref, slane_ref)
+    # the block's partial fills a (1, 1, 128) row: Mosaic blocks end in
+    # (8k | full, 128k | full) dims, which a (1, 1) scalar block is not
+    out_ref[...] = jnp.broadcast_to(jnp.sum(x[0] * pr + x[1] * pi),
+                                    (1, 1, 128))
 
 
 def _pl_term_inputs(amps, codes, ang, nq: int, offset: int, n: int,
                     conj: bool):
-    """(meta, fvals, view, s_row, s_lane) shared by the two Pallas term
-    kernels."""
+    """(meta, fvals, view, s_lane) shared by the two Pallas term kernels;
+    meta = (block row XOR, in-block row XOR, lane XOR, row parity mask)."""
     dt = amps.dtype
     R = 1 << (n - 7)
     fm_lo, fm_hi, zlo, zhi, ny = _direct_masks(codes, nq, offset, n)
@@ -552,11 +515,17 @@ def _pl_term_inputs(amps, codes, ang, nq: int, offset: int, n: int,
         fm = fm | (fm_hi << _GATHER_LO_BITS)
     fm_lane = (fm & jnp.uint32(127)).astype(jnp.int32)
     fm_row = (fm >> 7).astype(jnp.int32)
-    meta = jnp.stack([fm_row >> 8, fm_row & 255, fm_lane])
-    s_full = _parity_sign_dynamic(zlo, zhi, n, dt)
-    # parity factorises: s(r*128 + l) = s_row(r) * s_lane(l)
-    s_lane = s_full[:128].reshape(1, 128)
-    s_row = s_full.reshape(R, 128)[:, :1]
+    # parity factorises: s(r*128 + l) = s_row(r) * s_lane(l); the row
+    # mask (< 2^25 for n <= 32) rides the scalar prefetch
+    zm = zlo.astype(jnp.uint32)
+    zrow = zm >> 7
+    if n > _PAR_LO_BITS:
+        zrow = zrow | (zhi.astype(jnp.uint32) << (_PAR_LO_BITS - 7))
+    meta = jnp.stack([fm_row >> 8, fm_row & 255, fm_lane,
+                      zrow.astype(jnp.int32)])
+    s_lane = 1.0 - 2.0 * ((jax.lax.population_count(
+        jax.lax.iota(jnp.uint32, 128) & (zm & jnp.uint32(127)))
+        & jnp.uint32(1)).astype(dt)).reshape(1, 128)
     theta = jnp.where((fm_lo | fm_hi | zlo | zhi) == 0,
                       jnp.asarray(0.0, dt), ang)
     c_re, c_im = _iexp_factor(ny, dt)
@@ -564,7 +533,7 @@ def _pl_term_inputs(amps, codes, ang, nq: int, offset: int, n: int,
         c_im = -c_im
     fvals = jnp.stack([jnp.cos(0.5 * theta), jnp.sin(0.5 * theta),
                        c_re, c_im]).reshape(1, 4)
-    return meta, fvals, amps.reshape(2, R, 128), s_row, s_lane
+    return meta, fvals, amps.reshape(2, R, 128), s_lane
 
 
 def _pl_grid_spec(R, out_blockspec):
@@ -579,7 +548,6 @@ def _pl_grid_spec(R, out_blockspec):
             pl.BlockSpec((2, _PL_BR, 128), lambda i, meta: (0, i, 0)),
             pl.BlockSpec((2, _PL_BR, 128),
                          lambda i, meta: (0, i ^ meta[0], 0)),
-            pl.BlockSpec((_PL_BR, 1), lambda i, meta: (i, 0)),
             pl.BlockSpec((1, 128), lambda i, meta: (0, 0)),
         ],
         out_specs=out_blockspec,
@@ -589,24 +557,24 @@ def _pl_grid_spec(R, out_blockspec):
 def _expec_term_pallas(amps, codes, n: int):
     """Re <amps| P |amps> with a traced code row, one fused HBM pass:
     the kernel writes one partial per grid block and the (G,) partials
-    tree-reduce here under XLA — O(log G) error depth instead of the
-    former single-cell sequential accumulation's O(G)."""
+    tree-reduce here under XLA — O(log G) error depth instead of a
+    single-cell sequential accumulation's O(G)."""
     import jax
     import jax.experimental.pallas as pl
 
     from . import fused as _fused
 
-    meta, fvals, view, s_row, s_lane = _pl_term_inputs(
+    meta, fvals, view, s_lane = _pl_term_inputs(
         amps, codes, jnp.zeros((), amps.dtype), n, 0, n, conj=False)
     R = view.shape[1]
     out = pl.pallas_call(
         _pl_expec_kernel,
         grid_spec=_pl_grid_spec(
-            R, pl.BlockSpec((1, 1), lambda i, meta: (i, 0))),
-        out_shape=jax.ShapeDtypeStruct((R // _PL_BR, 1), view.dtype),
+            R, pl.BlockSpec((1, 1, 128), lambda i, meta: (i, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((R // _PL_BR, 1, 128), view.dtype),
         interpret=_fused._interpret_default(),
-    )(meta, fvals, view, view, s_row, s_lane)
-    return jnp.sum(out)
+    )(meta, fvals, view, view, s_lane)
+    return jnp.sum(out[:, 0, 0])
 
 
 def _direct_rotation_pallas(amps, codes, ang, nq: int, offset: int,
@@ -619,7 +587,7 @@ def _direct_rotation_pallas(amps, codes, ang, nq: int, offset: int,
 
     from . import fused as _fused
 
-    meta, fvals, view, s_row, s_lane = _pl_term_inputs(
+    meta, fvals, view, s_lane = _pl_term_inputs(
         amps, codes, ang, nq, offset, n, conj)
     R = view.shape[1]
     out = pl.pallas_call(
@@ -629,7 +597,7 @@ def _direct_rotation_pallas(amps, codes, ang, nq: int, offset: int,
                             lambda i, meta: (0, i, 0))),
         out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
         interpret=_fused._interpret_default(),
-    )(meta, fvals, view, view, s_row, s_lane)
+    )(meta, fvals, view, view, s_lane)
     return out.reshape(amps.shape)
 
 
@@ -708,6 +676,11 @@ def expec_pauli_sum_scan(amps, codes_seq, coeffs, *, num_qubits: int,
 
     n = num_qubits
     dt = amps.dtype
+    use_pl = not quad and _pl_routable(amps, n)
+    if not use_pl:
+        # the gather forms index the flat state (the Pallas term kernel
+        # views a canonical (2, 2^(n-14), 128, 128) state in place)
+        amps = amps.reshape(2, -1)
 
     if n > _DIRECT_MAX_N:
         def signed_norm(phi, zlo, zhi):
@@ -732,8 +705,6 @@ def expec_pauli_sum_scan(amps, codes_seq, coeffs, *, num_qubits: int,
     # split-axis gather + reduce otherwise.  Quad keeps the gather form:
     # its channel-split double-double accumulation needs the full
     # product vectors, not f32 block partials.
-    use_pl = not quad and _pl_routable(amps, n)
-
     def body(acc, inp):
         codes, coeff = inp
         if use_pl:

@@ -373,17 +373,17 @@ def _shard_payload_bytes(amps, mesh: Mesh) -> int:
     A batched (B, 2, N) register bank's shard carries all B elements'
     slices, so its exchange payload (and the telemetry byte accounting
     built on it) scales with the batch size."""
-    b = int(amps.shape[0]) if amps.ndim == 3 else 1
-    return (b * 2 * (int(amps.shape[-1]) // amp_axis_size(mesh))
-            * amps.dtype.itemsize)
+    return int(amps.size) // amp_axis_size(mesh) * amps.dtype.itemsize
 
 
-def exchange_pipelined(send, perm, combine_fn, *, chunks: int):
+def exchange_pipelined(send, perm, combine_fn, *, chunks: int,
+                       axis: int = -1):
     """Chunked double-buffered ppermute INSIDE a shard_map body.
 
-    Splits ``send`` into ``chunks`` equal contiguous pieces along its
-    LAST axis (= the top log2(chunks) bits of the per-shard amplitude
-    index) and software-pipelines the exchange:
+    Splits ``send`` into ``chunks`` equal contiguous pieces along
+    ``axis`` (default the LAST axis, = the top log2(chunks) bits of a
+    flat shard's amplitude index; the block axis 1 of a canonical shard)
+    and software-pipelines the exchange:
 
         prologue : ppermute chunk 0
         steady   : ppermute chunk i+1; combine chunk i   (i = 0..C-2)
@@ -401,15 +401,15 @@ def exchange_pipelined(send, perm, combine_fn, *, chunks: int):
     ``chunks`` <= 1 (or a non-dividing count) is the monolithic path:
     one ppermute, one combine — bit-identical output either way, since
     the combines are elementwise on disjoint chunks."""
-    m = int(send.shape[-1])
+    axis = axis % send.ndim
+    m = int(send.shape[axis])
     if chunks <= 1 or m % chunks or m // chunks == 0:
         recv = lax.ppermute(send, AMP_AXIS, perm)
         return combine_fn(0, send, recv)
     step = m // chunks
-    parts = jnp.split(send, chunks, axis=-1)
+    parts = jnp.split(send, chunks, axis=axis)
     in_flight = lax.ppermute(parts[0], AMP_AXIS, perm)     # prologue
     out = send
-    zeros = (0,) * (send.ndim - 1)
     for i in range(chunks):
         recv = in_flight
         if i + 1 < chunks:
@@ -420,8 +420,10 @@ def exchange_pipelined(send, perm, combine_fn, *, chunks: int):
         # a second full-payload staging buffer (measured on the CPU
         # dryrun), the chain lets buffer assignment grow the output in
         # place once the source chunks are dead
+        start = [0] * send.ndim
+        start[axis] = i * step
         out = lax.dynamic_update_slice(
-            out, combine_fn(i, parts[i], recv), zeros + (i * step,))
+            out, combine_fn(i, parts[i], recv), tuple(start))
     return out
 
 
@@ -445,10 +447,112 @@ def _swap_halves_in_shard(local, lb: int, mb: int, nloc: int, ndev: int,
         lv, recv.reshape(send.shape), 1 - u, axis=2).reshape(2, -1)
 
 
+# amplitude bits inside one (128, 128) block of a canonical shard
+_BLOCK_BITS = 14
+
+
+def remap_window_cap(nloc: int) -> int:
+    """Most distinct qubits one remap window may want shard-local.  A
+    bit inside a (128, 128) block swaps with a mesh bit through a
+    shard-sized relayout on the TPU (_swap_halves_canonical), which a
+    shard of 2^28 amplitudes or more (2 GiB f32; 8 GiB at 32 qubits on
+    four chips) cannot afford: its windows leave 14 slots unwanted, so
+    every eviction finds a block slot (plan_window_remap prefers those)
+    and the swap runs in place.  Smaller shards use every slot."""
+    return nloc - _BLOCK_BITS if nloc >= 2 * _BLOCK_BITS else nloc
+
+
+def _swap_halves_canonical(local, lb: int, mb: int, ndev: int,
+                           chunks: int = 1):
+    """_swap_halves_in_shard for a canonical (2, B, 128, 128) shard
+    (qureg.device_amps_shape), in place and chunk by chunk.
+
+    Each chunk is a static slice of blocks holding both halves of bit
+    ``lb``; the half to send is picked by a select on the shard's mesh
+    bit, not by a dynamic index (whose TPU compile grew with the shard),
+    and the combined chunk is written back where it was read, so the
+    TPU compiler runs a block bit's swap (lb >= 14) in place
+    (tests/test_chip_compile.py).  For a bit inside the block XLA may lay
+    the whole shard out anew, a shard-sized copy, which the window
+    planner keeps away from large shards (remap_window_cap)."""
+    u = ((lax.axis_index(AMP_AXIS) >> mb) & 1) == 1
+    perm = _hypercube_perm(ndev, mb)
+    nb = int(local.shape[1])
+    lo = 1 << max(lb - _BLOCK_BITS, 0)      # blocks per half-run
+    if lb >= _BLOCK_BITS and nb // (2 * lo) < chunks:
+        # few long half-runs: a chunk is a block range of one half-run
+        # and the same range of its partner run, two slices apart
+        per = max(1, min(chunks, nb // 2) * 2 * lo // nb)
+        nj = lo // per
+        spans = [(h * 2 * lo + j * nj, nj) for h in range(nb // (2 * lo))
+                 for j in range(per)]
+
+        def halves(cur, span):
+            return (lax.slice_in_dim(cur, span[0], span[0] + nj, axis=1),
+                    lax.slice_in_dim(cur, span[0] + lo, span[0] + lo + nj,
+                                     axis=1))
+
+        def combine(cur, span, recv):
+            # each kept half is read just before its own update
+            a = lax.slice_in_dim(cur, span[0], span[0] + nj, axis=1)
+            cur = lax.dynamic_update_slice(cur, jnp.where(u, recv, a),
+                                           (0, span[0], 0, 0))
+            b = lax.slice_in_dim(cur, span[0] + lo, span[0] + lo + nj,
+                                 axis=1)
+            return lax.dynamic_update_slice(cur, jnp.where(u, b, recv),
+                                            (0, span[0] + lo, 0, 0))
+    else:
+        # a chunk is a block range holding both halves of bit lb
+        chunks = max(1, min(chunks, nb // (2 * lo) if lb >= _BLOCK_BITS
+                            else nb))
+        step = nb // chunks
+        spans = [(c * step, step) for c in range(chunks)]
+        if lb >= _BLOCK_BITS:
+            pview, pax = (2, step // (2 * lo), 2, lo, 128, 128), 2
+        elif lb >= 7:            # a sublane bit
+            pview, pax = (2, step, 1 << (13 - lb), 2, 1 << (lb - 7), 128), 3
+        else:                    # a lane bit
+            pview, pax = (2, step, 128, 1 << (6 - lb), 2, 1 << lb), 4
+
+        def halves(cur, span):
+            piece = lax.slice_in_dim(cur, span[0], span[0] + step,
+                                     axis=1).reshape(pview)
+            return (lax.index_in_dim(piece, 0, pax, keepdims=False),
+                    lax.index_in_dim(piece, 1, pax, keepdims=False))
+
+        def combine(cur, span, recv):
+            a, b = halves(cur, span)
+            piece = jnp.stack([jnp.where(u, recv, a), jnp.where(u, b, recv)],
+                              axis=pax).reshape(2, step, 128, 128)
+            return lax.dynamic_update_slice(cur, piece, (0, span[0], 0, 0))
+
+    def send(cur, span):
+        a, b = halves(cur, span)
+        return lax.ppermute(jnp.where(u, a, b), AMP_AXIS, perm)
+
+    # the pipeline of exchange_pipelined: chunk i+1 is sent before chunk
+    # i is combined.  The combine reads its kept half from the newest
+    # chain value: a read of an older value would keep that whole
+    # version live and force XLA to copy the shard at every update.
+    cur = local
+    in_flight = send(cur, spans[0])
+    for i, span in enumerate(spans):
+        recv = in_flight
+        if i + 1 < len(spans):
+            in_flight = send(cur, spans[i + 1])
+        cur = combine(cur, span, recv)
+    return cur
+
+
 def amp_axis_size(mesh: Mesh) -> int:
     """Size of the amplitude axis — NOT mesh.devices.size: meshes may carry
     extra axes (e.g. the (dp, amps) training mesh)."""
     return int(mesh.shape[AMP_AXIS])
+
+
+def mesh_platform(mesh: Mesh) -> str:
+    """Platform of the mesh's devices ("tpu", "cpu", ...)."""
+    return mesh.devices.flat[0].platform
 
 
 def num_shard_bits(mesh: Mesh) -> int:
@@ -1753,11 +1857,16 @@ def _remap_in_shard(local, sigma: Tuple[int, ...], nloc: int, ndev: int,
     the standalone remap_sharded program and the fusion drain's
     ("remap", sigma) parts.
 
+    ``local`` is a flat (2, 2^nloc) shard or a canonical
+    (2, 2^(nloc-14), 128, 128) one (qureg.device_amps_shape), whose
+    mixed swaps run in place (_swap_halves_canonical).
+
     ``chunks``: (half_shard_chunks, full_shard_chunks); None resolves the
     per-op heuristic from the (static) per-shard payload size at trace
     time — the drain executor keys its compiled-program cache on
     exchange_config_key() so an env-override flip retraces."""
     r = int(math.log2(ndev))
+    canonical = local.ndim == 4
     mixed, local_perm, mesh_tau = decompose_sigma(sigma, nloc, r)
     t = topo.resolve(ndev)
     if t.dcn_bits and len(mixed) > 1:
@@ -1773,7 +1882,11 @@ def _remap_in_shard(local, sigma: Tuple[int, ...], nloc: int, ndev: int,
     ch_half = min(_pow2_floor(chunks[0]), 1 << max(nloc - 1, 0))
     ch_full = min(_pow2_floor(chunks[1]), 1 << nloc)
     for lb, mb in mixed:
-        local = _swap_halves_in_shard(local, lb, mb, nloc, ndev, ch_half)
+        if canonical:
+            local = _swap_halves_canonical(local, lb, mb, ndev, ch_half)
+        else:
+            local = _swap_halves_in_shard(local, lb, mb, nloc, ndev,
+                                          ch_half)
     if local_perm is not None:
         local = kernels.permute_qubits(local, num_qubits=nloc,
                                        perm=local_perm)
@@ -1786,7 +1899,8 @@ def _remap_in_shard(local, sigma: Tuple[int, ...], nloc: int, ndev: int,
 
         local = exchange_pipelined(
             local, [(i, dest(i)) for i in range(ndev)],
-            lambda i, own, rv: rv, chunks=ch_full)
+            lambda i, own, rv: rv, chunks=ch_full,
+            axis=1 if canonical else -1)
     return local
 
 
@@ -1906,7 +2020,10 @@ def plan_window_remap(num_qubits: int, nloc: int, perm: Tuple[int, ...],
     assert len(pool) >= len(need)  # guaranteed by |want_local| <= nloc
     if next_use is None:
         next_use = {}
-    pool.sort(key=lambda p: next_use.get(inv[p], 1 << 60), reverse=True)
+    # on a canonical shard, block slots first (remap_window_cap)
+    block = _BLOCK_BITS if nloc >= _BLOCK_BITS else 0
+    pool.sort(key=lambda p: (p >= block, next_use.get(inv[p], 1 << 60)),
+              reverse=True)
     sigma = list(range(n))
     for q in need:
         p_high = perm[q]

@@ -11,7 +11,6 @@ Set QT_SCALE_MONOLITHIC=1 for the old one-program path.
 
 Timing: steady-state best-of-N wall, device estimate = wall minus the
 measured scalar-fetch overhead, and a K-diff (2 circuits minus 1) arm.
-Results recorded in BASELINE.md / BENCH notes.
 
 Usage: python scripts/bench_scale.py rand:30 qft:30 ...
 """

@@ -1,7 +1,7 @@
 #!/bin/sh
 # Build the reference-QuEST baseline driver (scripts/ref_bench.c) against
 # the unmodified reference sources, CPU multithreaded backend, double
-# precision — the configuration BASELINE.md cites for vs_baseline.
+# precision — the configuration bench.py's vs_baseline divides by.
 set -e
 REF=${REF:-/root/reference}
 OUT=${OUT:-/root/repo/.refbuild}

@@ -1,4 +1,4 @@
-"""f64-on-TPU evidence for the BASELINE.md north star.
+"""f64-on-TPU evidence: the same workload in f32 and f64.
 
 Runs config 1 (12q hadamard + controlledRotateX chain + calcProbOfOutcome)
 and a config-2-shaped random circuit at qreal = double (set_precision(2),

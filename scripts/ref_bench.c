@@ -1,4 +1,4 @@
-/* Reference-QuEST baseline driver for BASELINE.md / bench.py vs_baseline.
+/* Reference-QuEST baseline driver for bench.py vs_baseline.
  *
  * Replicates the bench.py workload shape exactly: N-qubit state-vector,
  * DEPTH layers of (N single-qubit unitaries + brick-wall CNOT ladder),

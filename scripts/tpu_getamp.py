@@ -2,8 +2,8 @@
 after a chained fused-QFT plan leaves the state in the canonical tiled
 view, getAmp-class reads (ops/element.get_amp_pair) and a setAmps-class
 ranged write (set_amp_range) complete in milliseconds with NO full-state
-relayout — the access pattern that previously OOM'd at 30q by the
-round-3 analysis (BASELINE.md).
+re-layout — the access pattern that would otherwise need a second
+state at 30q.
 
 Correctness oracle: QFT of |0..0> is the uniform state, so EVERY
 amplitude must read 2^(-n/2) + 0i at any index.

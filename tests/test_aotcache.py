@@ -141,8 +141,8 @@ class TestInvalidationMatrix:
             else:
                 monkeypatch.setenv("QT_MEGAKERNEL", old)
 
-        # "auto" and "off" both plan megakernels off on the CPU dryrun
-        # mesh, so the observable flip here is forcing "on"
+        # the default plans no megakernels, so the observable flip here
+        # is forcing "on"
         self._flip_and_expect_miss(
             env, lambda: monkeypatch.setenv("QT_MEGAKERNEL", "on"),
             unflip)

@@ -597,7 +597,7 @@ def test_split_plan_sides_merges_adjacent_duals():
 def test_split_plan_sides_leaves_singletons_and_masked():
     """A lone dual pass must NOT split (2 x 1.25 ms > 2.1 ms), and
     mask/rank-tied passes are barriers — exactly why the rewrite never
-    engages on the 26q headline plan (see BASELINE.md round-4 profile)."""
+    engages on the 26q headline plan."""
     from quest_tpu import circuit as C
 
     rng = np.random.default_rng(10)
@@ -649,3 +649,30 @@ def test_split_plan_sides_multibit_lane_product_blocks_mask():
     # and the masked pass must have stayed a barrier (no merged A pass
     # crossing it): the first op must still be dual-side
     assert split[0][4] and split[0][5]
+
+
+def test_native_library_rebuilds_when_the_source_hash_changes(
+        tmp_path, monkeypatch):
+    """A library built from other source is never loaded: the recorded
+    content hash, not file times, decides the rebuild."""
+    import shutil
+
+    from quest_tpu import native
+
+    src = tmp_path / "scheduler.cc"
+    shutil.copy(native._SRC, src)
+    lib = tmp_path / "_qts.so"
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_LIB", str(lib))
+    monkeypatch.setattr(native, "_LIB_HASH", str(lib) + ".sha256")
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_failed", False)
+    assert native.get_lib() is not None
+    first = (tmp_path / "_qts.so.sha256").read_text()
+    assert first == native._source_hash()
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    monkeypatch.setattr(native, "_lib", None)
+    assert native.get_lib() is not None
+    assert (tmp_path / "_qts.so.sha256").read_text() == native._source_hash()
+    assert native._source_hash() != first

@@ -91,3 +91,27 @@ def test_environment_string_reports_exchange_config(monkeypatch):
     assert "ExchangeChunks=auto" in qt.getEnvironmentString(env)
     monkeypatch.setenv("QT_EXCHANGE_CHUNKS", "4")
     assert "ExchangeChunks=4" in qt.getEnvironmentString(env)
+
+
+def test_accelerator_default_is_the_checkout_dir(monkeypatch):
+    """Off the CPU, with no cache named, the cache lives at the fixed
+    <checkout>/.jax_cache (the directory .gitignore lists)."""
+    import os
+
+    for var in ("QT_NO_COMPILE_CACHE", "JAX_COMPILATION_CACHE_DIR",
+                "QT_COMPILE_CACHE", "QT_COMPILE_CACHE_DIR"):
+        monkeypatch.delenv(var, raising=False)
+    if jax.config.jax_compilation_cache_dir:
+        pytest.skip("cache already configured in this session")
+    monkeypatch.setattr(E.jax, "default_backend", lambda: "tpu")
+    made = []
+    monkeypatch.setattr(E.os, "makedirs", lambda p, exist_ok: made.append(p))
+    try:
+        E._enable_compilation_cache()
+        want = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(E.__file__))), ".jax_cache")
+        assert _configured() == want and made == [want]
+        assert os.path.basename(want) in open(os.path.join(
+            os.path.dirname(want), ".gitignore")).read()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)

@@ -269,7 +269,14 @@ class TestShardedFusion:
             assert len(q._fusion.gates) == 2
         assert abs(qt.calcProbOfOutcome(q, 15, 0) - 0.5) < 1e-6
         assert abs(qt.calcProbOfOutcome(q, 2, 0) - 0.5) < 1e-6
-        assert q._perm is None  # the read rematerialized canonical order
+        # probabilities read through the live permutation; a full state
+        # read rematerializes canonical order
+        assert q._perm is not None
+        amps = np.asarray(q.amps)
+        assert q._perm is None
+        expect = np.zeros(1 << 17)
+        expect[[0, 4, 1 << 15, (1 << 15) | 4]] = 0.5
+        np.testing.assert_allclose(amps[0], expect, atol=1e-6)
 
 
 class TestChannelCapture:

@@ -4,8 +4,7 @@ path (circuit._bit_reversal_big), plus the planner's k in {8,9} pruning.
 The sigma kernel runs in interpret mode at small n; the 30q reversal is
 validated at the INDEX level (composing each op's permutation semantics
 over random sample indices) since a 2^30 state cannot be materialized in
-CI.  On-chip equivalence vs the out-of-place path was verified at 28q on
-the real TPU (slices bit-identical; see BASELINE.md round-3 notes).
+CI.
 """
 
 import numpy as np
@@ -147,3 +146,29 @@ def test_chained_executor_matches_monolithic():
     ops = C.plan_to_device(C.plan_circuit(gates, n), jnp.float32)
     out = np.asarray(C.execute_plan_chained(fresh(), ops, n)).reshape(2, -1)
     np.testing.assert_array_equal(out, ref)
+
+
+# lane, sublane and block targets; the lowest block target sets how many
+# canonical rows one grid step covers (r = 1, 2, 8 and the whole state)
+@pytest.mark.parametrize("targets", [(3, 9, 15, 17), (0, 14), (16, 2, 8),
+                                     (17, 15, 16), (5, 11)])
+def test_diagonal_canonical_matches_dense(targets):
+    """fused.apply_diagonal_canonical (the in-place pass for a diagonal
+    gate no window covers) against a dense diagonal multiply, amplitude
+    by amplitude: the factor of index i is diag[bits of i at targets]."""
+    from quest_tpu.ops import fused
+
+    n, k = 18, len(targets)
+    rng = np.random.default_rng(sum(targets))
+    psi = rng.normal(size=1 << n) + 1j * rng.normal(size=1 << n)
+    d = np.exp(1j * rng.uniform(0, 2 * np.pi, size=1 << k))
+    idx = np.arange(1 << n)
+    code = sum(((idx >> t) & 1) << j for j, t in enumerate(targets))
+    want = psi * d[code]
+    state = jnp.asarray(np.stack([psi.real, psi.imag]).reshape(
+        2, 1 << (n - 14), 128, 128))
+    out = fused.apply_diagonal_canonical(
+        state, jnp.asarray(np.stack([d.real, d.imag])), num_qubits=n,
+        targets=targets, interpret=True)
+    got = np.asarray(out).reshape(2, -1)
+    np.testing.assert_allclose(got[0] + 1j * got[1], want, atol=1e-12)

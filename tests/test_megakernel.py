@@ -20,7 +20,6 @@ Covers the acceptance contract:
 """
 
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +30,6 @@ import quest_tpu as qt
 from quest_tpu import circuit as CIRC
 from quest_tpu import fusion as F
 from quest_tpu import introspect
-from quest_tpu import resilience as R
 from quest_tpu import telemetry as T
 from quest_tpu.ops import fused
 
@@ -208,11 +206,11 @@ class TestGrouping:
     def test_mode_parsing(self, monkeypatch):
         for raw, want in (("on", "on"), ("1", "on"), ("TRUE", "on"),
                           ("off", "off"), ("0", "off"), ("no", "off"),
-                          ("auto", "auto"), ("bogus", "auto")):
+                          ("auto", "off"), ("bogus", "off")):
             monkeypatch.setenv("QT_MEGAKERNEL", raw)
             assert fused.megakernel_mode() == want
         monkeypatch.delenv("QT_MEGAKERNEL")
-        assert fused.megakernel_mode() == "auto"
+        assert fused.megakernel_mode() == "off"
 
 
 class TestParity:
@@ -355,40 +353,20 @@ class TestDispatchPins:
 
 class TestFallbackLadder:
     def test_auto_gates_on_backend_and_dtype(self, monkeypatch):
-        monkeypatch.setenv("QT_MEGAKERNEL", "auto")
-        # pretend a real TPU whose lowering probe passed
-        monkeypatch.setattr(fused, "_interpret_default", lambda: False)
-        monkeypatch.setattr(fused, "_MEGA_OK", {"ok": True})
-        assert fused.megakernel_planning()
-        assert fused.megakernel_executable(jnp.float32)
-        assert not fused.megakernel_executable(jnp.float64)
-        # interpret-mode (non-TPU) backend: plan nothing, execute nothing
-        monkeypatch.setattr(fused, "_interpret_default", lambda: True)
-        assert not fused.megakernel_planning()
-        assert not fused.megakernel_executable(jnp.float32)
-        # the knob overrides both directions
-        monkeypatch.setenv("QT_MEGAKERNEL", "on")
-        assert fused.megakernel_executable(jnp.float64)
-        monkeypatch.setenv("QT_MEGAKERNEL", "off")
-        monkeypatch.setattr(fused, "_interpret_default", lambda: False)
-        assert not fused.megakernel_planning()
-        assert not fused.megakernel_executable(jnp.float32)
-
-    def test_probe_failure_lands_in_degradation_registry(self, monkeypatch):
-        """Force the one-shot Mosaic probe to really run on this (CPU)
-        backend: it must fail, downgrade megakernel_executable, and
-        record pallas-window-megakernel in the degradation registry."""
-        monkeypatch.setenv("QT_MEGAKERNEL", "auto")
-        monkeypatch.setattr(fused, "_interpret_default", lambda: False)
-        monkeypatch.setattr(fused, "_MEGA_OK", {})
-        monkeypatch.setattr(R, "DEGRADATIONS", {})
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            assert not fused.megakernel_executable(jnp.float32)
-        assert "pallas-window-megakernel" in R.degradation_report()
-        # cached: the second call must not re-probe (dict already decided)
-        assert fused._MEGA_OK == {"ok": False}
-        assert not fused.megakernel_lowering_ok()
+        """The default ("off"; "auto" now reads as off) plans no megawin
+        group on any backend (the v5e compiler refuses members the row
+        caps admit); only "on" forms them, and a planned group always
+        runs fused."""
+        for tpu in (False, True):
+            monkeypatch.setattr(fused, "_interpret_default", lambda t=tpu: not t)
+            monkeypatch.delenv("QT_MEGAKERNEL", raising=False)
+            assert fused.megakernel_mode() == "off"
+            assert not fused.megakernel_planning()
+            monkeypatch.setenv("QT_MEGAKERNEL", "off")
+            assert not fused.megakernel_planning()
+            monkeypatch.setenv("QT_MEGAKERNEL", "on")
+            assert fused.megakernel_planning()
+        assert not hasattr(fused, "megakernel_lowering_ok")
 
 
 class TestCollectives:

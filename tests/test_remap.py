@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import oracle
 import quest_tpu as qt
@@ -129,6 +130,54 @@ class TestRemapAlgebra:
             for k in range(i, j):
                 assert all(perm[b] < 3 for b in bits[k])
         assert sorted(final_perm) == list(range(6))
+
+
+class TestCanonicalShardRemap:
+    """Shards of 2^14+ amplitudes are held in the canonical
+    (2, B, 128, 128) shape and swap their halves in place, chunk by
+    chunk (dist._swap_halves_canonical); the result is the same bit
+    permutation the flat path applies."""
+
+    # lane bit, sublane bit, block bit with both halves in a chunk, block
+    # bit whose halves are a chunk apart (n = 20 over 8 devices: nloc 17,
+    # B = 8 blocks per shard)
+    @pytest.mark.parametrize("lb", [3, 9, 14, 16])
+    @pytest.mark.parametrize("chunks", [1, 4])
+    def test_canonical_swap_is_the_flat_swap(self, env, lb, chunks):
+        n = 20
+        nloc = n - 3
+        sigma = list(range(n))
+        sigma[lb], sigma[n - 2] = n - 2, lb
+        sigma = tuple(sigma)
+        vec = np.random.default_rng(lb).standard_normal((2, 1 << n))
+        shards = NamedSharding(env.mesh, P(None, "amps"))
+        flat = jax.device_put(vec, shards)
+        want = np.asarray(dist.remap_sharded(
+            flat, mesh=env.mesh, num_qubits=n, sigma=sigma,
+            chunks=(chunks, chunks)))
+        canon = jax.device_put(vec.reshape(2, 1 << (n - 14), 128, 128),
+                               shards)
+        got = dist.remap_sharded(canon, mesh=env.mesh, num_qubits=n,
+                                 sigma=sigma, chunks=(chunks, chunks))
+        assert got.shape == canon.shape
+        assert dist.decompose_sigma(sigma, nloc, 3)[0] == ((lb, 1),)
+        np.testing.assert_array_equal(np.asarray(got).reshape(2, -1), want)
+
+    def test_large_shard_windows_swap_block_bits_only(self):
+        """A 32-qubit register over four chips (8 GiB shards) plans every
+        remap of chip_smoke's circuit as swaps of block bits (>= 14),
+        the ones that run in place; a small shard may use any slot."""
+        import chip_smoke
+
+        n, nloc = 32, 30
+        bits = [op[2:3] if op[0] == "rot" else op[1:3]
+                for op in chip_smoke.random_circuit(n)]
+        assert dist.remap_window_cap(nloc) == nloc - 14
+        assert dist.remap_window_cap(27) == 27
+        segments, _perm = CIRC.plan_remap_windows(bits, n, nloc)
+        swapped = [lb for _ij, sigma, _p in segments if sigma is not None
+                   for lb, _mb in dist.decompose_sigma(sigma, nloc, 2)[0]]
+        assert swapped and min(swapped) >= 14
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,6 @@ import warnings
 import numpy as np
 import pytest
 
-import jax
-
 import quest_tpu as qt
 from quest_tpu import circuit as CIRC
 from quest_tpu import resilience as R
@@ -341,43 +339,6 @@ class TestRNGStateRoundTrip:
         qt.run_resumable(q3, _circuit(), ckpt, every=8)
         got = qt.measureSequence(q3, list(range(N)))[0]
         assert got == want
-
-
-class TestGracefulDegradation:
-    def test_pallas_probe_failure_records_downgrade(self, env, monkeypatch):
-        from quest_tpu.ops import paulis as P
-
-        monkeypatch.setattr(P, "_PALLAS_OK", {})
-        monkeypatch.setattr(R, "DEGRADATIONS", {})
-
-        def boom():
-            raise RuntimeError("mosaic lowering exploded")
-
-        monkeypatch.setattr(P, "_probe_pallas_lowering", boom)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            assert P.pallas_lowering_ok() is False
-        assert any("degraded" in str(x.message) for x in w)
-        # cached: no second warning
-        with warnings.catch_warnings(record=True) as w2:
-            warnings.simplefilter("always")
-            assert P.pallas_lowering_ok() is False
-        assert not w2
-        assert "pallas-direct-rotation" in qt.degradation_report()
-        assert "Degraded=[" in qt.getEnvironmentString(env)
-        # and the production router takes the gather path
-        amps = jax.numpy.zeros((2, 1 << P._PL_MIN_N), jax.numpy.float32)
-        assert not P._pl_routable(amps, P._PL_MIN_N)
-
-    def test_pallas_probe_success_reports_clean(self, env, monkeypatch):
-        from quest_tpu.ops import paulis as P
-
-        monkeypatch.setattr(P, "_PALLAS_OK", {})
-        monkeypatch.setattr(R, "DEGRADATIONS", {})
-        monkeypatch.setattr(P, "_probe_pallas_lowering", lambda: None)
-        assert P.pallas_lowering_ok() is True
-        assert qt.degradation_report() == {}
-        assert "Degraded" not in qt.getEnvironmentString(env)
 
 
 class TestFaultPlanParsing:

@@ -375,7 +375,7 @@ class TestExchangeAccounting:
         with qt.gateFusion(q):
             for a, b in bit_sets:
                 qt.multiQubitUnitary(q, [a, b], u)
-        _ = qt.calcProbOfOutcome(q, 0, 0)  # drains + rematerializes
+        _ = q.amps  # drains + rematerializes canonical order
         snap = T.snapshot()
         got_bytes = _sum(snap["counters"]["exchange_bytes_total"])
         got_count = _sum(snap["counters"]["exchanges_total"])
@@ -422,7 +422,7 @@ class TestExchangeAccounting:
         with qt.gateFusion(q):
             for a, b in bit_sets:
                 qt.multiQubitUnitary(q, [a, b], u)
-        _ = qt.calcProbOfOutcome(q, 0, 0)
+        _ = q.amps  # drains + rematerializes canonical order
         series = T.snapshot()["counters"]["exchange_bytes_total"]
         got_tier = {t: sum(v for k, v in series.items()
                            if f"tier={t}" in k) for t in ("ici", "dcn")}
