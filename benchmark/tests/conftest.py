@@ -1,0 +1,8 @@
+import os
+import sys
+
+# the benchmark's tests run on the CPU backend; the package is imported
+# from the checkout's root
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
