@@ -52,7 +52,7 @@ import itertools
 import math
 from dataclasses import dataclass
 from functools import lru_cache
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1105,15 +1105,30 @@ def group_megawins(ops: Sequence[tuple], num_qubits: int) -> List[tuple]:
     return out
 
 
+def _no_phase(name: str) -> None:
+    pass
+
+
 def plan_circuit(gates: Sequence[Gate], num_qubits: int,
                  use_native: Optional[bool] = None,
-                 planner: Optional[str] = None) -> List[tuple]:
+                 planner: Optional[str] = None,
+                 phase: Callable[[str], None] = _no_phase) -> List[tuple]:
     """Plan a gate list.
 
     ``planner``: 'windowed' (default — offset-window passes, zero
     relocation) or 'paged' (the segswap-relocation scheduler).  Overridable
     via QT_PLANNER.  The native C++ scheduler (native/scheduler.cc) is used
-    when built; Python fallback otherwise — identical algorithm/output."""
+    when built; Python fallback otherwise — identical algorithm/output.
+
+    ``phase``: the fusion drain's step marker (telemetry.phases), called
+    with the name of each planning step as it starts: ``fusion.analyse``
+    (the controlled-form rewrite and the per-gate ranks and flags the
+    native scheduler takes), ``fusion.schedule`` (the structural
+    scheduler; the Python windowed fallback, which rewrites, schedules
+    and materializes in one, and the paged planner run wholly in it),
+    ``fusion.materialize`` (the native plan's side, cross and mask
+    folds) and ``fusion.group`` (the side split and megawin grouping).
+    The default marks nothing, as for every caller outside a drain."""
     import os
 
     from . import native
@@ -1131,19 +1146,25 @@ def plan_circuit(gates: Sequence[Gate], num_qubits: int,
         if use_native and num_qubits >= WINDOW:
             # the controlled-form rewrite happens here so the C++ planner
             # sees the same (rewritten) gate stream as the Python one
+            phase("fusion.analyse")
             glist = rewrite_controlled_gates(list(gates))
+            xranks, flags = _gate_xranks(glist), _gate_flags(glist)
+            phase("fusion.schedule")
             structural = native.plan_native_windowed(
-                [g.targets for g in glist], num_qubits,
-                _gate_xranks(glist), _gate_flags(glist))
+                [g.targets for g in glist], num_qubits, xranks, flags)
             if structural is not None:
+                phase("fusion.materialize")
                 ops = materialize_windowed_plan(structural, glist)
         if ops is None:
+            phase("fusion.schedule")
             ops = plan_circuit_windowed(gates, num_qubits)
+        phase("fusion.group")
         if _side_split_enabled() and num_qubits >= WINDOW:
             ops = split_plan_sides(ops)
         if fused.megakernel_planning() and num_qubits >= WINDOW:
             ops = group_megawins(ops, num_qubits)
         return ops
+    phase("fusion.schedule")
     if use_native is None:
         use_native = native.native_available()
     if use_native:

@@ -164,7 +164,7 @@ def _plan_key(items, nloc: int, sweep_ok: bool, perm0=None, nsh: int = 0):
             tuple(parts))
 
 
-def _split_items(items, nloc: int, sweep_ok: bool):
+def _split_items(items, nloc: int, sweep_ok: bool, phase=C._no_phase):
     """items -> (program, arrays): ``program`` is a hashable tuple of
     ("plan", skeleton, n_arrays) / ("chan", kind, t, b) /
     ("chansweep", ((kind, t, b), ...)) parts executed in order; ``arrays``
@@ -172,7 +172,13 @@ def _split_items(items, nloc: int, sweep_ok: bool):
     appended per item at _run time, not here).  With ``sweep_ok``,
     consecutive sweep-eligible channels (ket bit < 14) collapse into ONE
     chansweep part — a few co-residency HBM sweeps for a whole noise
-    layer (fused.apply_pair_channel_sweep)."""
+    layer (fused.apply_pair_channel_sweep).
+
+    ``phase`` marks the planning steps of each gate segment
+    (telemetry.phases, circuit.plan_circuit): ``fusion.analyse`` takes
+    in the permutation-run classification, ``fusion.schedule`` the
+    lowering of a permutation run, ``fusion.group`` the split of each
+    plan into skeleton and arrays."""
     program = []
     arrays = []
     seg = []
@@ -182,15 +188,18 @@ def _split_items(items, nloc: int, sweep_ok: bool):
         if seg:
             if not _QUIET[0]:
                 _telemetry.observe("fusion_window_gates", len(seg))
+            phase("fusion.analyse")
             for kind, sub in _perm_runs(seg):
                 if kind == "perm":
                     # permutation run: matrix-free static lowering (§28)
                     # — its own window kind, no gate-matrix stacks
+                    phase("fusion.schedule")
                     ops = C.lower_permutation_run(sub, nloc)
                     if ops:
                         program.append(("perm", tuple(ops)))
                 else:
-                    ops = C.plan_circuit(list(sub), nloc)
+                    ops = C.plan_circuit(list(sub), nloc, phase=phase)
+                    phase("fusion.group")
                     skeleton, arrs = C.split_plan(ops)
                     program.append(("plan", skeleton, len(arrs)))
                     arrays.extend(arrs)
@@ -284,7 +293,8 @@ def _item_entry(it):
     return C.perm_item_entry(it.targets, it.mat)
 
 
-def _split_items_sharded(items, n: int, nloc: int, perm0, sweep_ok: bool):
+def _split_items_sharded(items, n: int, nloc: int, perm0, sweep_ok: bool,
+                         phase=C._no_phase):
     """Windows + ONE batched remap each for a SHARDED drain: group
     consecutive items whose cumulative qubit set fits the shard-local
     space (circuit.plan_remap_windows), emit a ("remap", sigma) part
@@ -292,14 +302,14 @@ def _split_items_sharded(items, n: int, nloc: int, perm0, sweep_ok: bool):
     to their physical bits and fold them with the ordinary local planner.
     The permutation persists across windows AND drains — no swap-back;
     canonical order rematerializes on the next state read (Qureg.amps).
-    Returns (program, arrays, final_perm)."""
+    Returns (program, arrays, final_perm); ``phase`` as _split_items."""
+    phase("fusion.analyse")
     entries = [_item_entry(it) for it in items]
+    phase("fusion.schedule")
     segments, final_perm = C.plan_remap_windows(entries, n, nloc, perm0)
     program: List[tuple] = []
     arrays: List[object] = []
     for (i, j), sigma, perm in segments:
-        if not _QUIET[0]:
-            _telemetry.observe("fusion_remap_window_items", j - i)
         if C._is_relabel_entry(entries[i]):
             # permutation fold (§28): items [i, j) composed straight into
             # the plan's final permutation — zero data motion, nothing to
@@ -308,6 +318,7 @@ def _split_items_sharded(items, n: int, nloc: int, perm0, sweep_ok: bool):
             continue
         if sigma is not None:
             program.append(("remap", sigma))
+        phase("fusion.analyse")
         sub = []
         for it in items[i:j]:
             if isinstance(it, ChannelItem):
@@ -321,7 +332,7 @@ def _split_items_sharded(items, n: int, nloc: int, perm0, sweep_ok: bool):
             else:
                 sub.append(C.Gate(tuple(perm[t] for t in it.targets),
                                   it.mat))
-        p2, a2 = _split_items(sub, nloc, sweep_ok)
+        p2, a2 = _split_items(sub, nloc, sweep_ok, phase)
         program.extend(p2)
         arrays.extend(a2)
     return tuple(program), tuple(arrays), final_perm
@@ -383,22 +394,26 @@ def _run(qureg, items) -> None:
         for it in items)
     from .ops import fused as _fusedmod
     sweep_ok = _fusedmod.channel_sweep_enabled(qureg.dtype)
-    key = _plan_key(items, nloc, sweep_ok, perm0, nsh)
-    hit = _plan_cache.get(key) if key is not None else None
+    with _telemetry.span("fusion.key"):
+        key = _plan_key(items, nloc, sweep_ok, perm0, nsh)
+        hit = _plan_cache.get(key) if key is not None else None
     if hit is not None:
         _telemetry.inc("fusion_plan_cache_hits_total")
         program, arrays, final_perm = hit
     else:
         _telemetry.inc("fusion_plan_cache_misses_total")
-        with _telemetry.span("fusion.plan", items=len(items)):
+        # the planning steps tile fusion.plan as its child spans
+        # (fusion.analyse / schedule / materialize / group)
+        with _telemetry.span("fusion.plan", items=len(items)), \
+                _telemetry.phases() as phase:
             if mats_batched:
                 program, arrays, final_perm = _plan_batched_items(
-                    items, bsz, n, nloc, nsh, perm0, sweep_ok)
+                    items, bsz, n, nloc, nsh, perm0, sweep_ok, phase)
             elif nsh:
                 program, arrays, final_perm = _split_items_sharded(
-                    items, n, nloc, perm0, sweep_ok)
+                    items, n, nloc, perm0, sweep_ok, phase)
             else:
-                program, arrays = _split_items(items, nloc, sweep_ok)
+                program, arrays = _split_items(items, nloc, sweep_ok, phase)
                 final_perm = None
         if key is not None:
             if len(_plan_cache) >= _PLAN_CACHE_MAX:
@@ -411,10 +426,13 @@ def _run(qureg, items) -> None:
     # is cleared in the finally).
     gov = None
     try:
-        gov = _gov.govern_drain(qureg, program, arrays, nloc=nloc, nsh=nsh)
-        _run_dispatch(qureg, items, program, arrays, gov,
-                      n=n, nsh=nsh, nloc=nloc, bsz=bsz, perm0=perm0,
-                      mats_batched=mats_batched, final_perm=final_perm)
+        with _telemetry.span("fusion.govern"):
+            gov = _gov.govern_drain(qureg, program, arrays, nloc=nloc,
+                                    nsh=nsh)
+        with _telemetry.span("fusion.dispatch"):
+            _run_dispatch(qureg, items, program, arrays, gov,
+                          n=n, nsh=nsh, nloc=nloc, bsz=bsz, perm0=perm0,
+                          mats_batched=mats_batched, final_perm=final_perm)
     finally:
         _gov.end_drain()
 
@@ -455,16 +473,16 @@ def _run_dispatch(qureg, items, program, arrays, gov, *, n, nsh, nloc,
         # §29 megakernel route accounting: one "mega" per megawin group
         # (ONE pallas_call = one HBM round-trip for its whole run), one
         # "fallback" per winfused pass still on the per-pass route while
-        # grouping is active.  The gauge is the drain's mean HBM
-        # round-trips per fusion window — the quantity the megakernel
-        # exists to shrink.
+        # grouping is active.  fusion_passes_total counts the state
+        # passes the plan parts run (a megawin group is one): over
+        # fusion_windows_total, the HBM round-trips per fusion window —
+        # the quantity the megakernel and the planner exist to shrink.
         from .ops import fused as _fusedops
 
-        mega = fallback = trips = plan_parts = 0
+        mega = fallback = trips = 0
         for part in program:
             if part[0] != "plan":
                 continue
-            plan_parts += 1
             for sk in part[1]:
                 trips += 1
                 if sk[0] == "megawin":
@@ -476,9 +494,8 @@ def _run_dispatch(qureg, items, program, arrays, gov, *, n, nsh, nloc,
         if fallback and _fusedops.megakernel_planning():
             _telemetry.inc("megakernel_dispatch_total", fallback,
                            route="fallback")
-        if plan_parts:
-            _telemetry.set_gauge("window_hbm_round_trips",
-                                 trips / plan_parts)
+        if trips:
+            _telemetry.inc("fusion_passes_total", trips)
         # permutation-family route accounting (§28): lowered window ops
         # count by kind (one coalesced transpose = relabel, static
         # xor/gather passes = gather); sharded relabel FOLDS — which
@@ -620,22 +637,24 @@ def _run_dispatch(qureg, items, program, arrays, gov, *, n, nsh, nloc,
 
 
 def _plan_batched_items(items, bsz: int, n: int, nloc: int, nsh: int,
-                        perm0, sweep_ok: bool):
+                        perm0, sweep_ok: bool, phase=C._no_phase):
     """Plan a drain whose items carry PER-ELEMENT matrices: each batch
     element is planned independently (the decomposition of a controlled
     gate is value-dependent) and all elements must produce the SAME
     program skeleton — the compiled executor is shared across the batch,
     only the pass arrays differ.  Returns (program, arrays, final_perm)
-    with each pass array stacked to a leading (B, ...) batch axis."""
+    with each pass array stacked to a leading (B, ...) batch axis;
+    ``phase`` as _split_items."""
     program = None
     final_perm = None
     per_elem = []
     for b in range(bsz):
         eit = _items_for_element(items, b)
         if nsh:
-            pb, ab, fp = _split_items_sharded(eit, n, nloc, perm0, sweep_ok)
+            pb, ab, fp = _split_items_sharded(eit, n, nloc, perm0, sweep_ok,
+                                              phase)
         else:
-            (pb, ab), fp = _split_items(eit, nloc, sweep_ok), None
+            (pb, ab), fp = _split_items(eit, nloc, sweep_ok, phase), None
         if b == 0:
             program, final_perm = pb, fp
         elif pb != program or fp != final_perm:

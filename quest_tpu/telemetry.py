@@ -13,8 +13,9 @@ process-wide registry every subsystem reports into:
 * **Metrics** — counters / gauges / histograms with labeled series
   (``inc``/``set_gauge``/``observe``).  The instrumented hot layers:
   api dispatch (``dispatch_total{family}``), the fusion drain
-  (``fusion_windows_total``, ``fusion_retrace_total``, plan-cache
-  hit/miss, window-size histograms), the distributed exchange sites
+  (``fusion_windows_total``, ``fusion_passes_total``,
+  ``fusion_retrace_total``, plan-cache hit/miss, window-size
+  histograms), the distributed exchange sites
   (``exchanges_total{op,chunks}``, ``exchange_bytes_total{op}`` — bytes
   are PER-SHARD ICI volume, matching circuit.remap_exchange_bytes's
   cost model), and the resilience layer (``checkpoint_commit_seconds``,
@@ -79,7 +80,7 @@ import math
 import os
 import threading
 import time
-from typing import Iterator, Optional
+from typing import Callable, Iterator, Optional
 
 OFF, ON, TRACE = 0, 1, 2
 _MODES = {"off": OFF, "on": ON, "trace": TRACE, "0": OFF, "1": ON}
@@ -235,8 +236,6 @@ HIST_BOUNDS = {
                                  60.0),
     "fusion_drain_gates": (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
     "fusion_window_gates": (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
-    "fusion_remap_window_items": (1, 2, 4, 8, 16, 32, 64, 128, 256, 512,
-                                  1024),
     # circuit-optimizer rewrite time (optimizer.optimize_items): pure
     # host work that should sit well under a drain's planning cost, so
     # the low decades get extra resolution
@@ -375,6 +374,41 @@ def span(name: str, **attrs) -> Iterator[None]:
             observe("span_seconds", dt, name=name)
             if _mode == TRACE:
                 _chrome_append(_chrome_event(name, t0, dt, attrs))
+
+
+def _no_phase(name: str) -> None:
+    pass
+
+
+@contextlib.contextmanager
+def phases() -> Iterator[Callable[[str], None]]:
+    """Consecutive spans over one region, for a routine that marks its
+    steps rather than nesting them: the yielded ``phase(name)`` ends the
+    span it opened last and opens :func:`span` ``name`` in its place, a
+    call naming the span already open keeps it open, and leaving the
+    region ends the last one.  So a step that a loop returns to extends
+    the open span instead of adding one, and the spans tile the region
+    with no gap between them.  ``phase`` is a no-op when telemetry is
+    off."""
+    if not _mode:
+        yield _no_phase
+        return
+    open_ = [None, None]    # (name, its span's context manager)
+
+    def phase(name: str) -> None:
+        if open_[0] == name:
+            return
+        if open_[1] is not None:
+            open_[1].__exit__(None, None, None)
+        cm = span(name)
+        cm.__enter__()
+        open_[:] = [name, cm]
+
+    try:
+        yield phase
+    finally:
+        if open_[1] is not None:
+            open_[1].__exit__(None, None, None)
 
 
 def write_trace(path: Optional[str] = None) -> Optional[str]:
@@ -934,8 +968,7 @@ def perf_report(env=None) -> str:
             lines.append(
                 f"  sparse inits: {_num(sparse)} "
                 f"(amps={_num(counter_total('sparse_init_amps_total'))})")
-    # §29 window megakernel: per-route dispatch split and the HBM
-    # round-trips the last drain paid per fused plan window
+    # §29 window megakernel: per-route dispatch split
     mega_n = counter_total("megakernel_dispatch_total")
     if mega_n:
         from .ops import fused as _fused
@@ -947,11 +980,15 @@ def perf_report(env=None) -> str:
         lines.append(
             f"window megakernel (§29, mode={_fused.megakernel_mode()}):")
         lines.append(f"  dispatches: total={_num(mega_n)} {by_route}")
-        trips = gauge_max("window_hbm_round_trips")
-        if trips is not None:
-            lines.append(
-                f"  hbm_round_trips/plan_window={trips:.3g} "
-                f"(1.0 = one read + one write per fused window)")
+    # the state passes the fusion drains dispatched per planned window
+    # (fusion_passes_total over fusion_windows_total)
+    windows = counter_total("fusion_windows_total")
+    if windows:
+        passes = counter_total("fusion_passes_total")
+        lines.append(
+            f"fusion passes: total={_num(passes)} "
+            f"hbm_round_trips/plan_window={passes / windows:.3g} "
+            f"(1.0 = one read + one write per fused window)")
     # §30 per-op wall-time attribution: each dispatched drain group's
     # wall time, keyed by its dominant plan-entry family (megawin /
     # winfused / permfast / channel / remap).  When the measured

@@ -162,7 +162,9 @@ def _drain_arm(env, flag, n, depth, us, reps):
         mega = int(T.counter_sum("megakernel_dispatch_total", route="mega"))
         fallback = int(T.counter_sum("megakernel_dispatch_total",
                                      route="fallback"))
-        trips = T.gauge_max("window_hbm_round_trips")
+        windows = T.counter_total("fusion_windows_total")
+        trips = (T.counter_total("fusion_passes_total") / windows
+                 if windows else None)
     return {"megakernel": flag, "seconds": round(best, 4),
             "drift": drift, "mega_dispatches": mega,
             "fallback_dispatches": fallback,

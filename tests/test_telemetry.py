@@ -199,6 +199,29 @@ class TestSpans:
         assert T.write_trace(str(tmp_path / "b.json")) is None
         assert not (tmp_path / "b.json").exists()
 
+    def test_phases_tile_the_region_and_extend_a_repeat(self, tmp_path):
+        T.configure("trace")
+        with T.phases() as phase:
+            phase("step.a")
+            phase("step.a")     # already open: extends it
+            phase("step.b")
+            phase("step.a")
+        with open(T.write_trace(str(tmp_path / "t.json"))) as f:
+            events = json.load(f)["traceEvents"]
+        assert [e["name"] for e in events] == ["step.a", "step.b", "step.a"]
+        for x, y in zip(events, events[1:]):
+            assert y["ts"] >= x["ts"] + x["dur"] - 1e-3
+        h = T.snapshot()["histograms"]["span_seconds"]
+        assert h["name=step.a"]["count"] == 2
+        assert h["name=step.b"]["count"] == 1
+
+    def test_phases_off_records_nothing(self):
+        T.configure("off")
+        with T.phases() as phase:
+            phase("step.a")
+        T.configure("on")
+        assert "span_seconds" not in T.snapshot()["histograms"]
+
 
 # ---------------------------------------------------------------------------
 # Prometheus exposition
@@ -903,6 +926,140 @@ class TestPerOpAttribution:
         routes = T.snapshot()["histograms"].get("plan_route_seconds", {})
         assert routes, "drain recorded no per-route attribution"
         assert T.counter_total("plan_route_dispatch_total") >= 1
+
+
+def _phase_circuit(q, seed: int) -> None:
+    """Three layers of rotations and controlled phases, buffered on
+    ``q``'s open fusion block: one dense segment, one plan part."""
+    n = q.num_qubits_represented
+    angles = np.random.default_rng(seed).uniform(0, 2 * np.pi, (3, n))
+    for layer in range(3):
+        for t in range(n):
+            qt.rotateY(q, t, float(angles[layer, t]))
+            qt.rotateZ(q, t, 0.7 * float(angles[layer, t]))
+        for t in range(layer % 2, n - 1, 2):
+            qt.controlledPhaseShift(q, t, t + 1, 0.3)
+
+
+_PLANNER_PHASES = ("fusion.analyse", "fusion.schedule", "fusion.materialize",
+                   "fusion.group")
+_DRAIN_PHASES = ("fusion.key", "fusion.govern", "fusion.dispatch")
+
+
+@pytest.fixture(scope="module")
+def phase_drains(tmp_path_factory):
+    """A 15-qubit single-device drain in trace mode, then the same drain
+    again (a plan-cache hit): per drain the Chrome events, the
+    fusion_passes_total it added and the program plan_items_quiet gives
+    for its items."""
+    prev = T.mode_name()
+    T.configure("trace")
+    out = []
+    try:
+        q = qt.createQureg(15, qt.createQuESTEnv(num_devices=1))
+        for i in range(2):
+            T.reset()
+            qt.startGateFusion(q)
+            _phase_circuit(q, 2024)
+            program = fusion.plan_items_quiet(q, list(q._fusion.gates))[0]
+            qt.stopGateFusion(q)
+            passes = T.counter_total("fusion_passes_total")
+            path = T.write_trace(str(tmp_path_factory.mktemp("drain")
+                                     / f"{i}.json"))
+            with open(path) as f:
+                events = json.load(f)["traceEvents"]
+            out.append({"events": events, "passes": passes,
+                        "program": program})
+    finally:
+        T.reset()
+        T.configure(prev)
+    return out
+
+
+class TestDrainPhaseSpans:
+    """The fusion drain's phase spans: the planner's steps inside
+    fusion.plan, the drain's own host work inside fusion.drain, and the
+    fusion_passes_total counter."""
+
+    @pytest.mark.parametrize("name,parent", (
+        [(n, "fusion.plan") for n in _PLANNER_PHASES]
+        + [(n, "fusion.drain") for n in _DRAIN_PHASES]))
+    def test_drain_records_phase_once_inside_parent(self, phase_drains,
+                                                    name, parent):
+        events = phase_drains[0]["events"]
+        mine = [e for e in events if e["name"] == name]
+        outer = [e for e in events if e["name"] == parent]
+        assert len(mine) == 1 and len(outer) == 1
+        (m,), (p,) = mine, outer
+        assert m["tid"] == p["tid"]
+        assert p["ts"] <= m["ts"]
+        assert m["ts"] + m["dur"] <= p["ts"] + p["dur"] + 1e-3
+
+    def test_planner_phases_tile_fusion_plan(self, phase_drains):
+        events = phase_drains[0]["events"]
+        plan = next(e for e in events if e["name"] == "fusion.plan")
+        steps = sum(e["dur"] for e in events if e["name"] in _PLANNER_PHASES)
+        assert steps >= 0.9 * plan["dur"]
+
+    @pytest.mark.parametrize("name", _PLANNER_PHASES)
+    def test_plan_cache_hit_records_no_planner_phase(self, phase_drains,
+                                                     name):
+        names = [e["name"] for e in phase_drains[1]["events"]]
+        assert "fusion.drain" in names and "fusion.plan" not in names
+        assert set(_DRAIN_PHASES) <= set(names)
+        assert name not in names
+
+    @pytest.mark.parametrize("drain", [0, 1], ids=["miss", "hit"])
+    def test_passes_counter_counts_skeleton_entries(self, phase_drains,
+                                                    drain):
+        d = phase_drains[drain]
+        want = sum(len(part[1]) for part in d["program"]
+                   if part[0] == "plan")
+        assert want > 0 and d["passes"] == want
+
+    @pytest.mark.parametrize("dry", ["plan_items_quiet", "explain_circuit",
+                                     "explain_memory"])
+    def test_dry_run_planning_records_nothing(self, dry, tmp_path):
+        from quest_tpu import governor
+
+        T.configure("trace")
+        q = qt.createQureg(15, qt.createQuESTEnv(num_devices=1))
+        qt.startGateFusion(q)
+        _phase_circuit(q, 2025)
+        items = list(q._fusion.gates)
+        T.reset()
+        if dry == "plan_items_quiet":
+            fusion.plan_items_quiet(q, items)
+        elif dry == "explain_circuit":
+            qt.explain_circuit(q)
+        else:
+            governor.explain_memory(q, items)
+        assert "span_seconds" not in T.snapshot()["histograms"]
+        assert T.counter_total("fusion_passes_total") == 0
+        assert T.write_trace(str(tmp_path / "t.json")) is None
+
+    def test_sharded_drain_plans_inside_fusion_plan(self, env, tmp_path):
+        T.configure("trace")
+        q = qt.createQureg(10, env)
+        qt.startGateFusion(q)
+        _phase_circuit(q, 2026)
+        qt.stopGateFusion(q)
+        with open(T.write_trace(str(tmp_path / "t.json"))) as f:
+            events = json.load(f)["traceEvents"]
+        (plan,) = [e for e in events if e["name"] == "fusion.plan"]
+        steps = [e for e in events if e["name"] in _PLANNER_PHASES]
+        assert {"fusion.analyse", "fusion.schedule"} <= {
+            e["name"] for e in steps}
+        for e in steps:
+            assert plan["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= plan["ts"] + plan["dur"] + 1e-3
+
+    def test_perf_report_prints_passes_per_window(self):
+        assert "hbm_round_trips/plan_window" not in T.perf_report()
+        T.inc("fusion_windows_total", 4)
+        T.inc("fusion_passes_total", 10)
+        assert ("fusion passes: total=10 hbm_round_trips/plan_window=2.5"
+                in T.perf_report())
 
 
 class TestMemoryWatermarkGauge:
