@@ -48,6 +48,7 @@ uses the native planner when the library is built (see native/__init__.py).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import math
 from dataclasses import dataclass
@@ -58,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from .ops import cplx, fused, kernels
 
 LANE = fused.LANE_QUBITS            # 7
@@ -341,7 +343,10 @@ def embed_in_cluster(mat_soa, bits: Tuple[int, ...]):
     dispatch and a host round trip."""
     row, col, mask = _embed_indices(tuple(bits))
     if isinstance(mat_soa, np.ndarray):
-        return mat_soa[:, row, col] * mask.astype(mat_soa.dtype)
+        # the advanced index behind a slice comes back non-C-contiguous
+        # (strides (8, 2048, 16)), which takes matmul off BLAS
+        return np.ascontiguousarray(
+            mat_soa[:, row, col] * mask.astype(mat_soa.dtype))
     m = jnp.asarray(mat_soa)
     return m[:, row, col] * jnp.asarray(mask, m.dtype)
 
@@ -359,6 +364,59 @@ def soa_matmul(a, b):
     re = jnp.matmul(a[0], b[0], precision=hi) - jnp.matmul(a[1], b[1], precision=hi)
     im = jnp.matmul(a[0], b[1], precision=hi) + jnp.matmul(a[1], b[0], precision=hi)
     return jnp.stack([re, im])
+
+
+_FOLDS_STRUCTURED = telemetry.counter_key("plan_folds_total",
+                                          path="structured")
+_FOLDS_DENSE = telemetry.counter_key("plan_folds_total", path="dense")
+# >0 while a dry run (explain_circuit, the governor's predictor,
+# fusion.plan_items_quiet) plans: fold_gate and the fusion drain's
+# per-window observations then record nothing, since a dry run mutates
+# no telemetry
+PLAN_QUIET: List[int] = [0]
+
+
+@contextlib.contextmanager
+def quiet_planning():
+    """Plan as a dry run: no fold inside counts in telemetry."""
+    PLAN_QUIET[0] += 1
+    try:
+        yield
+    finally:
+        PLAN_QUIET[0] -= 1
+
+
+def fold_gate(mat_soa, bits: Tuple[int, ...], acc):
+    """``embed_in_cluster(mat_soa, bits) @ acc`` for a SoA (2, 2^k, 2^k)
+    gate on cluster bits ``bits`` and a SoA (2, 128, 128) accumulator
+    term; ``acc`` None (identity) returns the embedding itself.
+
+    Concrete numpy operands contract the gate over its own k row bits
+    (2^k * 128^2 complex multiply-adds, not the dense 128^3 product of
+    the zero-padded embedding); traced or device operands keep the dense
+    soa_matmul.  Each product counts in ``plan_folds_total{path}``."""
+    if acc is None:
+        return embed_in_cluster(mat_soa, bits)
+    structured = isinstance(mat_soa, np.ndarray) and isinstance(acc,
+                                                                np.ndarray)
+    if not PLAN_QUIET[0]:
+        telemetry.inc_key(_FOLDS_STRUCTURED if structured else _FOLDS_DENSE)
+    if not structured:
+        return soa_matmul(embed_in_cluster(mat_soa, bits), acc)
+    k = len(bits)
+    dt = np.result_type(mat_soa, acc)
+    cdt = np.result_type(dt, np.complex64)
+    u = (mat_soa[0] + 1j * mat_soa[1]).astype(cdt).reshape((2,) * (2 * k))
+    a = (acc[0] + 1j * acc[1]).astype(cdt).reshape((2,) * LANE + (DIM,))
+    # row bit b of the cluster is axis LANE-1-b of ``a``; matrix bit p
+    # (targets[p], p = 0 least significant) is axis k-1-p (out) and
+    # 2k-1-p (in) of ``u``
+    out = np.tensordot(u, a, axes=([2 * k - 1 - p for p in range(k)],
+                                   [LANE - 1 - b for b in bits]))
+    out = np.moveaxis(out, list(range(k)),
+                      [LANE - 1 - bits[k - 1 - i] for i in range(k)])
+    out = out.reshape(DIM, DIM)
+    return np.stack([out.real, out.imag]).astype(dt, copy=False)
 
 
 _EYE128 = None
@@ -657,17 +715,17 @@ class _FoldAcc:
         self.count = 0
 
     def fold(self, cluster: str, bits: Tuple[int, ...], mat):
-        e = embed_in_cluster(mat, bits)
         accs = self.As if cluster == "A" else self.Bs
         for r in range(self.rank):
-            accs[r] = e if accs[r] is None else soa_matmul(e, accs[r])
+            accs[r] = fold_gate(mat, bits, accs[r])
         self.count += 1
 
     def fold_cross(self, phys: Tuple[int, ...], mat):
         """Fold a 2q gate with one lane and one sublane target; requires
         rank == 1 (caller flushes first otherwise)."""
         assert self.rank == 1
-        mat = jnp.asarray(mat)
+        if not isinstance(mat, np.ndarray):
+            mat = jnp.asarray(mat)
         if phys[0] < LANE:
             la, sb = phys[0], phys[1]
             def block(a, b):
@@ -680,12 +738,10 @@ class _FoldAcc:
         As, Bs = [], []
         for a in (0, 1):
             for b in (0, 1):
-                ea = embed_in_cluster(block(a, b), (la,))
                 eb_np = np.zeros((2, 2, 2))
                 eb_np[0, a, b] = 1.0
-                eb = embed_in_cluster(eb_np, (sb - LANE,))
-                As.append(ea if A0 is None else soa_matmul(ea, A0))
-                Bs.append(eb if B0 is None else soa_matmul(eb, B0))
+                As.append(fold_gate(block(a, b), (la,), A0))
+                Bs.append(fold_gate(eb_np, (sb - LANE,), B0))
         self.As, self.Bs = As, Bs
         self.rank = _CROSS_RANK
         self.count += 1
@@ -719,10 +775,9 @@ class _WinAcc:
         self.mask: Optional[np.ndarray] = None  # complex (128, 128)
 
     def fold_side(self, side: str, bits: Tuple[int, ...], mat):
-        e = embed_in_cluster(mat, bits)
         accs = self.As if side == "A" else self.Bs
         for r in range(self.rank):
-            accs[r] = e if accs[r] is None else soa_matmul(e, accs[r])
+            accs[r] = fold_gate(mat, bits, accs[r])
         if side == "A":
             self.a_used = True
         else:
@@ -753,13 +808,9 @@ class _WinAcc:
                     pairs.append((lane_m, win_m))
         As, Bs = [], []
         for lane_m, win_m in pairs:
-            ea = embed_in_cluster(lane_m, (lane_bit,))
-            eb = embed_in_cluster(win_m, (win_bit,))
             for r in range(self.rank):
-                As.append(ea if self.As[r] is None
-                          else soa_matmul(ea, self.As[r]))
-                Bs.append(eb if self.Bs[r] is None
-                          else soa_matmul(eb, self.Bs[r]))
+                As.append(fold_gate(lane_m, (lane_bit,), self.As[r]))
+                Bs.append(fold_gate(win_m, (win_bit,), self.Bs[r]))
         self.As, self.Bs = As, Bs
         self.rank = len(As)
         self.a_used = True

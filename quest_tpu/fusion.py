@@ -97,11 +97,6 @@ def drain(qureg) -> None:
 _PLAN_CACHE_MAX = 64
 _plan_cache: dict = {}
 
-# >0 while a dry-run (explain_circuit's memory section / the governor
-# predictor) is planning: the per-window telemetry observations below
-# are suppressed and nothing is inserted into _plan_cache — the
-# explain contract is NO telemetry mutation (plan_items_quiet)
-_QUIET: List[int] = [0]
 
 
 class ChannelItem:
@@ -186,7 +181,7 @@ def _split_items(items, nloc: int, sweep_ok: bool, phase=C._no_phase):
 
     def flush_gates():
         if seg:
-            if not _QUIET[0]:
+            if not C.PLAN_QUIET[0]:
                 _telemetry.observe("fusion_window_gates", len(seg))
             phase("fusion.analyse")
             for kind, sub in _perm_runs(seg):
@@ -718,8 +713,7 @@ def plan_items_quiet(qureg, items):
     if hit is not None:
         program, arrays, final_perm = hit
         return program, arrays, final_perm, nloc, nsh
-    _QUIET[0] += 1
-    try:
+    with C.quiet_planning():
         if mats_batched:
             program, arrays, final_perm = _plan_batched_items(
                 items, bsz, n, nloc, nsh, perm0, sweep_ok)
@@ -729,8 +723,6 @@ def plan_items_quiet(qureg, items):
         else:
             program, arrays = _split_items(items, nloc, sweep_ok)
             final_perm = None
-    finally:
-        _QUIET[0] -= 1
     return program, arrays, final_perm, nloc, nsh
 
 

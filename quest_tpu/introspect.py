@@ -127,7 +127,9 @@ def _segment_stats(items, nloc=None, perm=None) -> tuple:
             else:
                 plan_parts += 1
                 if count_mega:
-                    for op in C.plan_circuit(list(sub), nloc):
+                    with C.quiet_planning():
+                        ops = C.plan_circuit(list(sub), nloc)
+                    for op in ops:
                         if op[0] == "megawin":
                             mega_groups += 1
                             mega_ops += len(op[1])
@@ -229,9 +231,10 @@ def _optimizer_section(orig_items, opt_items, ostats, *, n, nloc, nsh,
                 isinstance(g.mat, np.ndarray) and g.mat.ndim == 3
                 for g in gates0):
             gates1 = [it for it in opt_items if isinstance(it, C.Gate)]
-            wb = C.stats(C.plan_circuit(gates0, nloc))["total_passes"]
-            wa = C.stats(C.plan_circuit(gates1, nloc))["total_passes"] \
-                if gates1 else 0
+            with C.quiet_planning():
+                wb = C.stats(C.plan_circuit(gates0, nloc))["total_passes"]
+                wa = C.stats(C.plan_circuit(gates1, nloc))["total_passes"] \
+                    if gates1 else 0
             if not changed:
                 wa = wb
             section["windows_before"] = int(wb)

@@ -989,6 +989,15 @@ def perf_report(env=None) -> str:
             f"fusion passes: total={_num(passes)} "
             f"hbm_round_trips/plan_window={passes / windows:.3g} "
             f"(1.0 = one read + one write per fused window)")
+    # the planner's gate-into-term products (circuit.fold_gate): concrete
+    # gates contract on their own bits, traced ones take the dense
+    # 128x128x128 product
+    folds = counter_total("plan_folds_total")
+    if folds:
+        by_path = " ".join(
+            f"{p}={_num(counter_sum('plan_folds_total', path=p))}"
+            for p in ("structured", "dense"))
+        lines.append(f"plan folds: total={_num(folds)} {by_path}")
     # §30 per-op wall-time attribution: each dispatched drain group's
     # wall time, keyed by its dominant plan-entry family (megawin /
     # winfused / permfast / channel / remap).  When the measured

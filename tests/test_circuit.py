@@ -144,6 +144,137 @@ class TestEmbedding:
         np.testing.assert_allclose(cu, expect, atol=1e-12)
 
 
+class TestFoldGate:
+    """circuit.fold_gate: the planner's gate-into-term product, contracted
+    on the gate's own bits for concrete numpy operands."""
+
+    @pytest.mark.parametrize("dtype,atol", [(np.float32, 1e-5),
+                                            (np.float64, 1e-12)],
+                             ids=["f32", "f64"])
+    @pytest.mark.parametrize("bits", [(0,), (3,), (6,), (5, 2), (0, 6),
+                                      (1, 4), (6, 3, 1), (0, 5, 2)],
+                             ids=str)
+    def test_matches_dense_product(self, bits, dtype, atol):
+        rng = np.random.default_rng(sum(b << (3 * i)
+                                        for i, b in enumerate(bits)))
+        mat = cplx.soa(random_unitary(len(bits), rng)).astype(dtype)
+        acc = rng.standard_normal((2, 128, 128)).astype(dtype)
+        want = C.soa_matmul(C.embed_in_cluster(mat, bits), acc)
+        got = C.fold_gate(mat, bits, acc)
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+
+    def test_identity_term_takes_contiguous_embedding(self):
+        mat = cplx.soa(random_unitary(2, np.random.default_rng(3)))
+        got = C.fold_gate(mat, (5, 2), None)
+        assert got.flags.c_contiguous
+        np.testing.assert_array_equal(got, C.embed_in_cluster(mat, (5, 2)))
+
+    def test_concrete_plan_never_takes_the_dense_product(self, monkeypatch):
+        """A concrete 20-qubit plan folds every gate on its own bits:
+        soa_matmul is never called from the fold path, and each product
+        counts once as plan_folds_total{path=structured}."""
+        import sys
+
+        from quest_tpu import telemetry as T
+
+        callers = []
+        products = []
+        soa_matmul, fold_gate = C.soa_matmul, C.fold_gate
+
+        def counting_matmul(a, b):
+            callers.append(sys._getframe(1).f_code.co_name)
+            return soa_matmul(a, b)
+
+        def spying_fold(mat, bits, acc):
+            if acc is not None:
+                products.append(bits)
+            return fold_gate(mat, bits, acc)
+
+        monkeypatch.setattr(C, "soa_matmul", counting_matmul)
+        monkeypatch.setattr(C, "fold_gate", spying_fold)
+        prev = T.mode_name()
+        T.configure("on")
+        T.reset()
+        try:
+            gates = _layered_circuit(np.random.default_rng(20), 20, 3)
+            ops = C.plan_circuit(gates, 20)
+            structured = T.counter_value("plan_folds_total",
+                                         path="structured")
+            dense = T.counter_value("plan_folds_total", path="dense")
+        finally:
+            T.reset()
+            T.configure(prev)
+        assert any(op[0] == "winfused" for op in ops)
+        assert "fold_gate" not in callers
+        assert products and structured == len(products)
+        assert dense == 0
+
+    def test_paged_cross_fold_stays_structured(self):
+        """_FoldAcc (the paged planner's accumulator) keeps a concrete
+        lane-x-sublane gate in numpy: its block products and every later
+        fold take the structured path and agree with device operands."""
+        import jax.numpy as jnp
+
+        from quest_tpu import telemetry as T
+
+        rng = np.random.default_rng(5)
+        u1 = cplx.soa(random_unitary(1, rng))
+        u2 = cplx.soa(random_unitary(2, rng))
+
+        def fold_all(cast):
+            acc = C._FoldAcc()
+            acc.fold("A", (2,), cast(u1))
+            acc.fold("B", (4,), cast(u1))
+            acc.fold_cross((3, 9), cast(u2))
+            acc.fold("A", (6,), cast(u1))
+            return acc.stacks()
+
+        prev = T.mode_name()
+        T.configure("on")
+        T.reset()
+        try:
+            got = fold_all(lambda m: m)
+            structured = T.counter_value("plan_folds_total",
+                                         path="structured")
+            dense = T.counter_value("plan_folds_total", path="dense")
+        finally:
+            T.reset()
+            T.configure(prev)
+        assert all(isinstance(s, np.ndarray) for s in got)
+        assert dense == 0 and structured > 0
+        want = fold_all(jnp.asarray)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, np.asarray(w), atol=1e-12)
+
+    def test_traced_gate_takes_the_dense_product(self):
+        import jax
+
+        from quest_tpu import telemetry as T
+
+        def plan(m):
+            ops = C.plan_circuit([C.Gate((q,), m) for q in range(3)], 14)
+            return [op[2] for op in ops if op[0] == "winfused"]
+
+        mat = cplx.soa(random_unitary(1, np.random.default_rng(4)))
+        prev = T.mode_name()
+        T.configure("on")
+        T.reset()
+        try:
+            (a,) = jax.jit(plan)(mat.astype(np.float32))
+            structured = T.counter_value("plan_folds_total",
+                                         path="structured")
+            dense = T.counter_value("plan_folds_total", path="dense")
+        finally:
+            T.reset()
+            T.configure(prev)
+        assert dense == 2 and structured == 0
+        u = cplx.unsoa(mat)
+        want = np.kron(np.kron(np.eye(16), u), np.kron(u, u))
+        np.testing.assert_allclose(cplx.unsoa(np.asarray(a[0])), want,
+                                   atol=1e-5)
+
+
 class TestScheduler:
     @pytest.mark.parametrize("n,depth", [(14, 2), (15, 3), (16, 2)])
     def test_e2e_matches_gatewise(self, n, depth):

@@ -950,8 +950,8 @@ _DRAIN_PHASES = ("fusion.key", "fusion.govern", "fusion.dispatch")
 def phase_drains(tmp_path_factory):
     """A 15-qubit single-device drain in trace mode, then the same drain
     again (a plan-cache hit): per drain the Chrome events, the
-    fusion_passes_total it added and the program plan_items_quiet gives
-    for its items."""
+    fusion_passes_total and plan_folds_total it added and the program
+    plan_items_quiet gives for its items."""
     prev = T.mode_name()
     T.configure("trace")
     out = []
@@ -964,12 +964,14 @@ def phase_drains(tmp_path_factory):
             program = fusion.plan_items_quiet(q, list(q._fusion.gates))[0]
             qt.stopGateFusion(q)
             passes = T.counter_total("fusion_passes_total")
+            folds = {p: T.counter_value("plan_folds_total", path=p)
+                     for p in ("structured", "dense")}
             path = T.write_trace(str(tmp_path_factory.mktemp("drain")
                                      / f"{i}.json"))
             with open(path) as f:
                 events = json.load(f)["traceEvents"]
             out.append({"events": events, "passes": passes,
-                        "program": program})
+                        "folds": folds, "program": program})
     finally:
         T.reset()
         T.configure(prev)
@@ -1017,6 +1019,15 @@ class TestDrainPhaseSpans:
                    if part[0] == "plan")
         assert want > 0 and d["passes"] == want
 
+    @pytest.mark.parametrize("drain", [0, 1], ids=["miss", "hit"])
+    def test_fold_counter_counts_structured_products(self, phase_drains,
+                                                     drain):
+        """A planned drain folds its concrete gates on their own bits
+        (circuit.fold_gate); a plan-cache hit folds nothing."""
+        folds = phase_drains[drain]["folds"]
+        assert folds["dense"] == 0
+        assert (folds["structured"] > 0) == (drain == 0)
+
     @pytest.mark.parametrize("dry", ["plan_items_quiet", "explain_circuit",
                                      "explain_memory"])
     def test_dry_run_planning_records_nothing(self, dry, tmp_path):
@@ -1036,6 +1047,7 @@ class TestDrainPhaseSpans:
             governor.explain_memory(q, items)
         assert "span_seconds" not in T.snapshot()["histograms"]
         assert T.counter_total("fusion_passes_total") == 0
+        assert T.counter_total("plan_folds_total") == 0
         assert T.write_trace(str(tmp_path / "t.json")) is None
 
     def test_sharded_drain_plans_inside_fusion_plan(self, env, tmp_path):
@@ -1059,6 +1071,13 @@ class TestDrainPhaseSpans:
         T.inc("fusion_windows_total", 4)
         T.inc("fusion_passes_total", 10)
         assert ("fusion passes: total=10 hbm_round_trips/plan_window=2.5"
+                in T.perf_report())
+
+    def test_perf_report_prints_plan_folds_by_path(self):
+        assert "plan folds" not in T.perf_report()
+        T.inc("plan_folds_total", 7, path="structured")
+        T.inc("plan_folds_total", 2, path="dense")
+        assert ("plan folds: total=9 structured=7 dense=2"
                 in T.perf_report())
 
 
