@@ -621,6 +621,18 @@ def calcExpecPauliSum(qureg: Qureg, allPauliCodes, termCoeffs, workspace: Option
             qureg.amps, cj, num_qubits=n, codes_flat=codes,
             num_terms=num_terms, quad=quad
         )
+    elif (_explicit_sharded(qureg) and not quad
+          and set(codes) <= {P.PAULI_I, P.PAULI_Z}):
+        # I/Z strings on a sharded register: one read pass over the
+        # shards in their device shape (the flat view is a second state
+        # per chip); device_amps() canonicalises a live permutation with
+        # one batched remap, so the next drain starts from canonical
+        # order and repeats its plan (docs/design.md §32)
+        amps = qureg.device_amps()
+        val = P.expec_z_sum(
+            amps, P.z_sum_masks(np.reshape(codes, (num_terms, n)),
+                                amps.shape),
+            jnp.asarray(cj, amps.dtype))
     elif _gspmd_pallas_unsafe(qureg) and not _explicit_sharded(qureg):
         # opted-out GSPMD mode on a real TPU mesh: the scan's Pallas
         # product layers cannot partition there — per-term kernels

@@ -1669,7 +1669,8 @@ def remap_exchange_bytes(sigma: Tuple[int, ...], num_qubits: int, nloc: int,
     from .parallel import dist as PAR
 
     r = num_qubits - nloc
-    mixed, _local_perm, mesh_tau = PAR.decompose_sigma(sigma, nloc, r)
+    mixed, _local_perm, mesh_tau = PAR.decompose_sigma(sigma, nloc, r,
+                                                       itemsize)
     shard = 2 * (1 << nloc) * itemsize          # SoA: re + im planes
     total = len(mixed) * (shard // 2)
     if mesh_tau is not None:

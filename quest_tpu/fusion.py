@@ -455,6 +455,54 @@ def _group_route(gprog) -> str:
     return "other"
 
 
+def _reconcile_remaps(qureg, items, program, *, n, nsh, nloc, bsz,
+                      perm0) -> None:
+    """Count a sharded drain's window remaps (exchanges_total /
+    exchange_bytes_total{op="window_remap"}, per tier) and reconcile them
+    against an independent re-plan through the cost model."""
+    from . import introspect as _introspect
+    from .parallel import dist as PAR
+    from .parallel import topology as _topo
+
+    bw = max(bsz, 1)  # each batch element exchanges its own amps
+    itemsize = np.dtype(qureg.dtype).itemsize
+    ck = str(PAR.exchange_config_key() or "auto")
+    topology = _topo.resolve(1 << nsh)
+    meas_c0 = _telemetry.counter_sum("exchanges_total",
+                                     op="window_remap")
+    meas_b0 = _telemetry.counter_sum("exchange_bytes_total",
+                                     op="window_remap")
+    meas_t0 = {t: _telemetry.counter_sum(
+        "exchange_bytes_total", op="window_remap", tier=t)
+        for t in _topo.TIERS}
+    for part in program:
+        if part[0] != "remap":
+            continue
+        # per-tier exchange classes straight from the cost model the
+        # tests pin (dist.remap_exchange_tiers sums exactly to
+        # remap_exchange_count / remap_exchange_bytes)
+        for tier, (cnt, b) in PAR.remap_exchange_tiers(
+                part[1], nloc, nsh, itemsize, topology).items():
+            if cnt or b:
+                _telemetry.record_exchange(
+                    "window_remap", cnt * bw, b * bw,
+                    chunks=ck, tier=tier)
+    # any disagreement with the re-plan is model drift (introspect,
+    # docs/design.md §21)
+    _introspect.reconcile_drain(
+        bit_sets=[_item_entry(it) for it in items],
+        n=n, nloc=nloc, nsh=nsh, perm0=perm0, itemsize=itemsize,
+        batch=bsz,
+        measured_count=_telemetry.counter_sum(
+            "exchanges_total", op="window_remap") - meas_c0,
+        measured_bytes=_telemetry.counter_sum(
+            "exchange_bytes_total", op="window_remap") - meas_b0,
+        measured_chunks=ck,
+        measured_tier_bytes={t: _telemetry.counter_sum(
+            "exchange_bytes_total", op="window_remap", tier=t)
+            - meas_t0[t] for t in _topo.TIERS})
+
+
 def _run_dispatch(qureg, items, program, arrays, gov, *, n, nsh, nloc,
                   bsz, perm0, mats_batched, final_perm) -> None:
     """Telemetry accounting + dispatch of a planned drain, in (possibly
@@ -517,55 +565,11 @@ def _run_dispatch(qureg, items, program, arrays, gov, *, n, nsh, nloc,
                         "permutation_gates_total",
                         route="exchange" if ex else "relabel")
         if nsh:
-            bw = max(bsz, 1)  # each batch element exchanges its own amps
-            # window-remap ICI accounting at dispatch time: each
-            # ("remap", sigma) part's per-shard exchange classes and
-            # bytes come from the same cost model the tests pin
-            # (circuit.remap_exchange_bytes / dist.decompose_sigma)
-            from .parallel import dist as PAR
-
-            from .parallel import topology as _topo
-
-            itemsize = np.dtype(qureg.dtype).itemsize
-            ck = str(PAR.exchange_config_key() or "auto")
-            topology = _topo.resolve(1 << nsh)
-            meas_c0 = _telemetry.counter_sum("exchanges_total",
-                                             op="window_remap")
-            meas_b0 = _telemetry.counter_sum("exchange_bytes_total",
-                                             op="window_remap")
-            meas_t0 = {t: _telemetry.counter_sum(
-                "exchange_bytes_total", op="window_remap", tier=t)
-                for t in _topo.TIERS}
-            for part in program:
-                if part[0] != "remap":
-                    continue
-                sigma = part[1]
-                # per-tier exchange classes straight from the same cost
-                # model the tests pin (dist.remap_exchange_tiers sums
-                # exactly to remap_exchange_count/remap_exchange_bytes)
-                for tier, (cnt, b) in PAR.remap_exchange_tiers(
-                        sigma, nloc, nsh, itemsize, topology).items():
-                    if cnt or b:
-                        _telemetry.record_exchange(
-                            "window_remap", cnt * bw, b * bw,
-                            chunks=ck, tier=tier)
-            # reconcile the drain's measured window-remap deltas against
-            # an independent re-plan through the cost model — any
-            # disagreement is model drift (introspect, docs/design.md §21)
-            from . import introspect as _introspect
-
-            _introspect.reconcile_drain(
-                bit_sets=[_item_entry(it) for it in items],
-                n=n, nloc=nloc, nsh=nsh, perm0=perm0, itemsize=itemsize,
-                batch=bsz,
-                measured_count=_telemetry.counter_sum(
-                    "exchanges_total", op="window_remap") - meas_c0,
-                measured_bytes=_telemetry.counter_sum(
-                    "exchange_bytes_total", op="window_remap") - meas_b0,
-                measured_chunks=ck,
-                measured_tier_bytes={t: _telemetry.counter_sum(
-                    "exchange_bytes_total", op="window_remap", tier=t)
-                    - meas_t0[t] for t in _topo.TIERS})
+            # the remap accounting and its reconciliation: host work on
+            # every sharded drain, plan-cache hits included
+            with _telemetry.span("fusion.reconcile"):
+                _reconcile_remaps(qureg, items, program, n=n, nsh=nsh,
+                                  nloc=nloc, bsz=bsz, perm0=perm0)
     probs = tuple(it.prob for it in items if isinstance(it, ChannelItem))
     from .ops import fused as _fused
     if nsh:

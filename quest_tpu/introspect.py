@@ -156,7 +156,8 @@ def _sigma_cost(sigma, n: int, nloc: int, nsh: int, itemsize: int,
     / the PIPELINE_MIN_BYTES policy via dist.remap_chunk_plan)."""
     from .parallel import dist as PAR
 
-    mixed, _lp, mesh_tau = PAR.decompose_sigma(tuple(sigma), nloc, nsh)
+    mixed, _lp, mesh_tau = PAR.decompose_sigma(tuple(sigma), nloc, nsh,
+                                               itemsize)
     ch_half, ch_full = PAR.remap_chunk_plan(nloc, itemsize, backend=backend)
     # per-interconnect-tier refinement of the same model (QT_TOPOLOGY;
     # single-host arrangements put everything under "ici")
@@ -165,7 +166,8 @@ def _sigma_cost(sigma, n: int, nloc: int, nsh: int, itemsize: int,
         "sigma": [int(p) for p in sigma],
         "mixed_swaps": len(mixed),
         "mesh_permute": mesh_tau is not None,
-        "exchanges": PAR.remap_exchange_count(tuple(sigma), nloc, nsh),
+        "exchanges": PAR.remap_exchange_count(tuple(sigma), nloc, nsh,
+                                              itemsize),
         "exchange_bytes": int(C.remap_exchange_bytes(
             tuple(sigma), n, nloc, itemsize)),
         "tier_bytes": {t: int(b) for t, (_c, b) in tiers.items()},
@@ -211,7 +213,8 @@ def _optimizer_section(orig_items, opt_items, ostats, *, n, nloc, nsh,
             if fperm is not None and list(fperm) != list(range(n)):
                 sigmas.append(PAR.canonical_sigma(tuple(fperm)))
             for sigma in sigmas:
-                count += PAR.remap_exchange_count(tuple(sigma), nloc, nsh)
+                count += PAR.remap_exchange_count(tuple(sigma), nloc, nsh,
+                                                  itemsize)
                 for t, b in C.remap_exchange_bytes_tiers(
                         tuple(sigma), n, nloc, itemsize).items():
                     tiers[t] = tiers.get(t, 0) + b
@@ -828,7 +831,7 @@ def _predict_cached(bit_key, n: int, nloc: int, nsh: int, perm_key,
     for _ij, sigma, _perm in segments:
         if sigma is None:
             continue
-        count += PAR.remap_exchange_count(tuple(sigma), nloc, nsh)
+        count += PAR.remap_exchange_count(tuple(sigma), nloc, nsh, itemsize)
         nbytes += C.remap_exchange_bytes(tuple(sigma), n, nloc, itemsize)
         for t, (_c, b) in PAR.remap_exchange_tiers(
                 tuple(sigma), nloc, nsh, itemsize, topology).items():

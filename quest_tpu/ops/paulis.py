@@ -747,3 +747,48 @@ def diag_from_z_hamil(zmasks_lo, zmasks_hi, coeffs, *, num_qubits: int,
         acc0 = jax.lax.with_sharding_constraint(acc0, sharding)
     acc, _ = jax.lax.scan(body, acc0, (zmasks_lo, zmasks_hi, coeffs))
     return acc
+
+
+def z_sum_masks(codes_seq, shape) -> "np.ndarray":
+    """(T, axes) uint32 parity masks of I/Z code rows over the index axes
+    of a state held in ``shape``: flat (2, 2^n) has one axis holding bits
+    [0, n); the canonical (2, 2^(n-14), 128, 128) has rows (bits 14..),
+    sublanes (bits 7..13) and lanes (bits 0..6)."""
+    import numpy as np
+
+    z = (np.asarray(codes_seq) == PAULI_Z).astype(np.uint64)
+    widths = [int(d).bit_length() - 1 for d in shape[1:]]
+    out = np.zeros((z.shape[0], len(widths)), np.uint64)
+    lo = z.shape[1]
+    for ax, w in enumerate(widths):
+        lo -= w
+        out[:, ax] = (z[:, lo:lo + w] << np.arange(w, dtype=np.uint64)).sum(1)
+    return out.astype(np.uint32)
+
+
+@jax.jit
+def expec_z_sum(amps, masks, coeffs):
+    """Re <psi| sum_t c_t Z_t |psi> for I/Z-only Pauli strings, on the
+    state where it lies: ``amps`` flat or in the canonical device shape,
+    on one device or sharded over the mesh (the partitioner gives each
+    device its shard's rows and adds the partial sums), with no copy of
+    the state.  ``masks``: z_sum_masks of the code rows for this shape.
+    Per term one read pass: the parity sign comes from per-axis index
+    iotas, rows are summed before the rows' sums."""
+    dt = amps.dtype
+    shape = amps.shape[1:]
+
+    def body(acc, inp):
+        m, c = inp
+        par = jnp.uint32(0)
+        for ax in range(len(shape)):
+            idx = jax.lax.broadcasted_iota(jnp.uint32, shape, ax)
+            par = par + jax.lax.population_count(idx & m[ax])
+        s = (1 - 2 * (par & jnp.uint32(1)).astype(jnp.int32)).astype(dt)
+        w = s * (amps[0] * amps[0] + amps[1] * amps[1])
+        rows = jnp.sum(w, axis=tuple(range(1, len(shape)))) \
+            if len(shape) > 1 else w
+        return acc + c.astype(dt) * jnp.sum(rows), None
+
+    total, _ = jax.lax.scan(body, jnp.zeros((), dt), (masks, coeffs))
+    return total

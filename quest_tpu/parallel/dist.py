@@ -451,6 +451,21 @@ def _swap_halves_in_shard(local, lb: int, mb: int, nloc: int, ndev: int,
 _BLOCK_BITS = 14
 
 
+def route_residue(nloc: int, itemsize: Optional[int] = None) -> bool:
+    """Whether a remap's local residue on a shard of 2^nloc amplitudes
+    goes through a mesh bit as in-place half-shard swaps
+    (decompose_sigma): the shard and the axis permutation's second copy
+    of it exceed the per-chip HBM budget (governor.budget_bytes; no
+    budget is known on the CPU).  ``itemsize``: bytes of one real
+    amplitude, the active precision's by default."""
+    from .. import governor, precision
+
+    if itemsize is None:
+        itemsize = jnp.dtype(precision.real_dtype()).itemsize
+    budget = governor.budget_bytes()
+    return budget is not None and 2 * (2 << nloc) * itemsize > budget
+
+
 def remap_window_cap(nloc: int) -> int:
     """Most distinct qubits one remap window may want shard-local.  A
     bit inside a (128, 128) block swaps with a mesh bit through a
@@ -1750,7 +1765,8 @@ def _fused_qft_runs_sharded(amps, *, mesh: Mesh, num_qubits: int,
 # ---------------------------------------------------------------------------
 
 
-def decompose_sigma(sigma: Tuple[int, ...], nloc: int, r: int):
+def decompose_sigma(sigma: Tuple[int, ...], nloc: int, r: int,
+                    itemsize: Optional[int] = None):
     """Split a physical bit permutation (``sigma[p]`` = destination
     position of the bit currently at physical position ``p``) into the
     cheapest exchange classes — the class folding of _reverse_run_sharded
@@ -1764,6 +1780,14 @@ def decompose_sigma(sigma: Tuple[int, ...], nloc: int, r: int):
       * mesh   : everything left on the mesh side, ONE composed full-shard
         ppermute (``mesh_tau[b]`` = destination mesh bit of coordinate
         bit b).
+
+    Where the chip cannot hold the axis permutation's second copy of
+    the shard (route_residue: 8 GiB shards of ``itemsize``-byte reals at
+    32 qubits over four v5e chips), a local residue that leaves the bits
+    inside a (128, 128) block in place is routed through mesh bit 0
+    instead, each of its cycles of k bits as k + 1 more mixed swaps,
+    which run in place (_swap_halves_canonical).  Those swaps share
+    their mesh bit, so the mixed list is an ORDERED sequence.
 
     Returns (mixed, local_perm | None, mesh_tau | None), applied in that
     order."""
@@ -1783,6 +1807,21 @@ def decompose_sigma(sigma: Tuple[int, ...], nloc: int, r: int):
         from_mesh.discard(m)
         mixed.append((l, m - nloc))
         cur[l], cur[m] = cur[m], cur[l]
+    if (r and nloc > _BLOCK_BITS and cur[:nloc] != list(range(nloc))
+            and cur[:_BLOCK_BITS] == list(range(_BLOCK_BITS))
+            and route_residue(nloc, itemsize)):
+        for start in range(_BLOCK_BITS, nloc):
+            if cur[start] == start:
+                continue
+            # the cycle start -> cur[start] -> ... through the slot of
+            # mesh bit 0: each swap lands the slot's bit at its
+            # destination and picks up the next one
+            cycle = [start]
+            while cur[cycle[-1]] != start:
+                cycle.append(cur[cycle[-1]])
+            for l in cycle + [start]:
+                mixed.append((l, 0))
+                cur[l], cur[nloc] = cur[nloc], cur[l]
     local_perm = None
     if cur[:nloc] != list(range(nloc)):
         inv = [0] * nloc
@@ -1796,7 +1835,8 @@ def decompose_sigma(sigma: Tuple[int, ...], nloc: int, r: int):
     return tuple(mixed), local_perm, mesh_tau
 
 
-def remap_exchange_count(sigma: Tuple[int, ...], nloc: int, r: int) -> int:
+def remap_exchange_count(sigma: Tuple[int, ...], nloc: int, r: int,
+                         itemsize: Optional[int] = None) -> int:
     """Number of exchange programs one remap of ``sigma`` dispatches —
     one half-shard ppermute per mixed transposition plus one composed
     full-shard ppermute when a residual mesh permute remains.  This is
@@ -1804,7 +1844,8 @@ def remap_exchange_count(sigma: Tuple[int, ...], nloc: int, r: int) -> int:
     record per (unbatched) remap; introspect.predict_window_exchanges
     re-derives drain telemetry from it (companion of
     circuit.remap_exchange_bytes on the count axis)."""
-    mixed, _local_perm, mesh_tau = decompose_sigma(tuple(sigma), nloc, r)
+    mixed, _local_perm, mesh_tau = decompose_sigma(tuple(sigma), nloc, r,
+                                                   itemsize)
     return len(mixed) + (1 if mesh_tau is not None else 0)
 
 
@@ -1818,7 +1859,8 @@ def remap_exchange_tiers(sigma: Tuple[int, ...], nloc: int, r: int,
     Tier sums equal the flat (remap_exchange_count,
     remap_exchange_bytes) pair exactly."""
     t = topology if topology is not None else topo.resolve(1 << r)
-    mixed, _local_perm, mesh_tau = decompose_sigma(tuple(sigma), nloc, r)
+    mixed, _local_perm, mesh_tau = decompose_sigma(tuple(sigma), nloc, r,
+                                                   itemsize)
     shard = 2 * (1 << nloc) * itemsize
     parts = {"ici": [0, 0], "dcn": [0, 0]}
     for _lb, mb in mixed:
@@ -1867,15 +1909,19 @@ def _remap_in_shard(local, sigma: Tuple[int, ...], nloc: int, ndev: int,
     exchange_config_key() so an env-override flip retraces."""
     r = int(math.log2(ndev))
     canonical = local.ndim == 4
-    mixed, local_perm, mesh_tau = decompose_sigma(sigma, nloc, r)
+    mixed, local_perm, mesh_tau = decompose_sigma(sigma, nloc, r,
+                                                  local.dtype.itemsize)
     t = topo.resolve(ndev)
-    if t.dcn_bits and len(mixed) > 1:
+    if (t.dcn_bits and len(mixed) > 1
+            and len({b for lm in mixed for b in (lm[0], nloc + lm[1])})
+            == 2 * len(mixed)):
         # DCN-overlap schedule (§17 generalized, docs/design.md §25):
         # issue the slow cross-host half-shard swaps FIRST so XLA's
         # latency-hiding scheduler overlaps their transfers against the
         # subsequent intra-host swaps and the local permute.  Mixed
-        # transpositions touch disjoint (local, mesh) bit pairs, so any
-        # ordering computes the identical state.
+        # transpositions on disjoint (local, mesh) bit pairs compute the
+        # identical state in any order; a routed residue's swaps share
+        # their mesh bit and keep theirs.
         mixed = tuple(sorted(mixed, key=lambda lm: lm[1] < t.ici_bits))
     if chunks is None:
         chunks = remap_chunk_plan(nloc, local.dtype.itemsize)
@@ -1913,7 +1959,10 @@ def remap_sharded(amps, *, mesh: Mesh, num_qubits: int,
     """ONE batched physical-bit permutation of a sharded register: at most
     (#local<->mesh crossings) chunk-pipelined half-shard exchanges + one
     per-shard axis permutation + one composed (chunked) full-shard
-    ppermute, regardless of how many gates the window it serves contains.
+    ppermute, regardless of how many gates the window it serves contains;
+    where the chip cannot hold the axis permutation's copy of the shard,
+    k + 1 more half-shard exchanges per cycle of k local bits in its
+    place (decompose_sigma, route_residue).
     This is the communication the window planner schedules ONCE per window
     where the reference pays two half-shard exchanges per sharded-target
     gate (QuEST_cpu_distributed.c:1447-1545)."""

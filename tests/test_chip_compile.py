@@ -373,3 +373,48 @@ def test_pipelined_exchange_transient_below_monolithic(mesh4):
     assert mono >= 2 * shard_bytes
     for c in (2, 4, 8):
         assert temp(c) < mono, c
+
+
+def test_sharded_z_read_in_place_32q(mesh4):
+    """calcExpecPauliSum's I/Z-string read of the 32-qubit state over the
+    four-chip mesh: one pass over each 8 GiB shard in its device shape,
+    partial sums only (the flat view would be a second state a chip)."""
+    state = _state(NamedSharding(mesh4, P(None, AMP_AXIS)), N32, 2)
+    rep = NamedSharding(mesh4, P())
+    masks = jax.ShapeDtypeStruct((1, 3), jnp.uint32, sharding=rep)
+    coeffs = jax.ShapeDtypeStruct((1,), jnp.float32, sharding=rep)
+    compiled = PAULI.expec_z_sum.lower(state, masks, coeffs).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes - ma.alias_size_in_bytes \
+        >= SHARD32_BYTES
+    assert ma.temp_size_in_bytes < SMALL, ma.temp_size_in_bytes
+    assert "all-reduce" in compiled.as_text()
+
+
+def test_canonical_read_remap_in_place_32q(mesh4, monkeypatch):
+    """The read that canonicalises a live permutation at 32 qubits over
+    four chips: the drain leaves block bits permuted among themselves,
+    and the remap back runs as ordered half-shard swaps in place (an
+    axis permutation would copy the 8 GiB shard, which the chip's HBM
+    budget, stated here as on the chip, has no room for)."""
+    monkeypatch.setenv("QT_HBM_BUDGET_BYTES", str(V5E_HBM_BYTES))
+    env = QuESTEnv(mesh=mesh4, rank=0, num_ranks=4, seeds=(),
+                   topology=TOPO.resolve(4))
+    q = Qureg(N32, env, is_density_matrix=False)
+    fusion.start_gate_fusion(q)
+    chip_smoke.apply_circuit(qt, q, chip_smoke.random_circuit(N32, layers=4))
+    _program, _arrays, final_perm, nloc, nsh = fusion.plan_items_quiet(
+        q, list(q._fusion.gates))
+    sigma = PAR.canonical_sigma(tuple(final_perm))
+    mixed, local_perm, _tau = PAR.decompose_sigma(sigma, nloc, nsh)
+    assert local_perm is None and mixed
+    chunks = PAR.remap_chunk_plan(nloc, 4, backend="tpu")
+    state = _state(NamedSharding(mesh4, P(None, AMP_AXIS)), N32, 2)
+    compiled = PAR._remap_sharded.lower(
+        state, mesh=mesh4, num_qubits=N32, sigma=sigma,
+        chunks=chunks).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == SHARD32_BYTES
+    assert SHARD32_BYTES + ma.temp_size_in_bytes <= V5E_HBM_BYTES, \
+        ma.temp_size_in_bytes
+    assert "collective-permute" in compiled.as_text()

@@ -29,6 +29,8 @@ from quest_tpu.ops import fused as F
 from quest_tpu.parallel import dist
 
 N = 6  # 64 amps over 8 devices -> nloc = 3: qubits 3, 4, 5 are sharded
+# the HBM a v5e program may use, as its compiler reports it ("15.75G")
+V5E_HBM_BYTES = int(15.75 * (1 << 30))
 ATOL = 1e-12
 
 _COLLECTIVE_OPS = (
@@ -162,6 +164,93 @@ class TestCanonicalShardRemap:
         assert got.shape == canon.shape
         assert dist.decompose_sigma(sigma, nloc, 3)[0] == ((lb, 1),)
         np.testing.assert_array_equal(np.asarray(got).reshape(2, -1), want)
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_large_shard_residue_routes_through_a_mesh_bit(
+            self, seed, monkeypatch):
+        """At 8 GiB f32 shards (32 qubits over four v5e chips) a remap
+        whose local residue keeps the block bits in place decomposes into
+        ordered half-shard swaps of block bits only, no axis permutation
+        (its second copy does not fit the chip), and the swaps replayed
+        in order realise sigma; the cost model counts each of them."""
+        n, nloc, r = 32, 30, 2
+        monkeypatch.setenv("QT_HBM_BUDGET_BYTES", str(V5E_HBM_BYTES))
+        rng = np.random.default_rng(seed)
+        sigma = tuple(list(range(14))
+                      + [int(b) for b in rng.permutation(range(14, n))])
+        mixed, local_perm, mesh_tau = dist.decompose_sigma(sigma, nloc, r,
+                                                           4)
+        assert local_perm is None
+        assert mixed and min(lb for lb, _mb in mixed) >= 14
+        held = list(range(n))     # held[p]: the bit now at position p
+        for lb, mb in mixed:
+            held[lb], held[nloc + mb] = held[nloc + mb], held[lb]
+        if mesh_tau is not None:
+            top = held[nloc:]
+            for b, dst in enumerate(mesh_tau):
+                held[nloc + dst] = top[b]
+        assert [held[sigma[p]] for p in range(n)] == list(range(n))
+        assert dist.remap_exchange_count(sigma, nloc, r, 4) == (
+            len(mixed) + (mesh_tau is not None))
+
+    @pytest.mark.parametrize("nloc,itemsize,budget,routed", [
+        (30, 4, V5E_HBM_BYTES, True),      # 8 GiB shard: no room for a copy
+        (29, 4, V5E_HBM_BYTES, False),     # 4 GiB shard and its copy fit
+        (29, 8, V5E_HBM_BYTES, True),      # the same shard in f64: 8 GiB
+        (30, 4, None, False),              # no budget known (the CPU)
+    ])
+    def test_residue_route_follows_the_chip_memory(
+            self, monkeypatch, nloc, itemsize, budget, routed):
+        """The residue is routed exactly where the shard and the axis
+        permutation's copy of it exceed the per-chip HBM budget, and the
+        cost model counts the path the remap takes."""
+        if budget is None:
+            monkeypatch.delenv("QT_HBM_BUDGET_BYTES", raising=False)
+        else:
+            monkeypatch.setenv("QT_HBM_BUDGET_BYTES", str(budget))
+        assert dist.route_residue(nloc, itemsize) == (
+            routed and budget is not None)
+        r = 2
+        sigma = list(range(nloc + r))
+        sigma[20], sigma[21], sigma[22] = 21, 22, 20    # a 3-cycle
+        mixed, local_perm, _tau = dist.decompose_sigma(tuple(sigma), nloc,
+                                                       r, itemsize)
+        assert (local_perm is None) == routed
+        assert len(mixed) == (4 if routed else 0)
+        assert dist.remap_exchange_count(tuple(sigma), nloc, r,
+                                         itemsize) == len(mixed)
+
+    @pytest.mark.parametrize("chunks", [1, 4])
+    def test_routed_residue_is_the_axis_permutation(self, env, monkeypatch,
+                                                    chunks):
+        """The routed swaps give the state the axis permutation gives: a
+        20-qubit register over 8 devices (canonical shards, block bits
+        14..16) with an HBM budget below twice its 2 MiB shard."""
+        n, nloc = 20, 17
+        # a local 3-cycle of block bits and a block bit <-> mesh crossing
+        sigma = list(range(n))
+        sigma[14], sigma[15], sigma[16] = 16, 14, 15
+        sigma[13], sigma[18] = 18, 13
+        sigma = tuple(sigma)
+        vec = np.random.default_rng(chunks).standard_normal((2, 1 << n))
+        shards = NamedSharding(env.mesh, P(None, "amps"))
+        canon = vec.reshape(2, 1 << (n - 14), 128, 128)
+        # out index bit sigma[p] = in index bit p (axis 1 + k holds bit
+        # n - 1 - k of the index)
+        axes = [0] * n
+        for p in range(n):
+            axes[n - 1 - sigma[p]] = 1 + n - 1 - p
+        want = vec.reshape((2,) + (2,) * n).transpose(
+            [0] + axes).reshape(canon.shape)
+        monkeypatch.delenv("QT_HBM_BUDGET_BYTES", raising=False)
+        assert dist.decompose_sigma(sigma, nloc, 3)[1] is not None
+        monkeypatch.setenv("QT_HBM_BUDGET_BYTES", str(3 << 20))
+        mixed, local_perm, _tau = dist.decompose_sigma(sigma, nloc, 3)
+        assert local_perm is None and len(mixed) == 1 + 4
+        got = dist.remap_sharded(jax.device_put(canon, shards),
+                                 mesh=env.mesh, num_qubits=n, sigma=sigma,
+                                 chunks=(chunks, chunks))
+        np.testing.assert_array_equal(np.asarray(got), want)
 
     def test_large_shard_windows_swap_block_bits_only(self):
         """A 32-qubit register over four chips (8 GiB shards) plans every
