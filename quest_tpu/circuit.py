@@ -1093,11 +1093,9 @@ MEGA_MAT_BYTES = 4 << 20
 
 def _winfused_mat_bytes(op) -> int:
     """f32 VMEM bytes of one winfused pass's matrix operands as the
-    megakernel stages them (dual-side passes upload 256x256 real reps)."""
-    rank = int(np.shape(op[2])[0])
-    dual = op[4] and op[5]
-    per = 2 * (2 * DIM) * (2 * DIM) * 4 if dual else 2 * 2 * DIM * DIM * 4
-    nbytes = rank * per
+    megakernel stages them: each side's planes [Re, Im, Re + Im]
+    (fused._gauss_planes)."""
+    nbytes = int(np.shape(op[2])[0]) * 2 * 3 * DIM * DIM * 4
     if len(op) > 6 and op[6] is not None:
         nbytes += 2 * DIM * DIM * 4
     return nbytes

@@ -18,10 +18,11 @@ the kernel applies A (right-contraction over lanes) and B (left-contraction
 over sublanes) to each block while it is VMEM-resident: two MXU matmuls,
 one HBM read + one write, regardless of how many gates were folded in.
 
-Complex arithmetic stays SoA (ops/cplx.py): the two channels are
-concatenated along the contracted axis and each cluster matrix becomes the
-256x256 real representation [[Re,Im],[-Im,Re]] (lanes) / [[Re,-Im],[Im,Re]]
-(sublanes), so each cluster costs exactly one real matmul.
+Complex arithmetic stays SoA (ops/cplx.py).  The cluster kernels
+concatenate the two channels along the contracted axis and make each
+cluster matrix the 256x256 real representation [[Re,Im],[-Im,Re]] (lanes)
+/ [[Re,-Im],[Im,Re]] (sublanes), one real matmul a side; the window pass
+takes three 128-wide real products a side instead (_window_block_body).
 
 Gates on qubits >= 14 are handled by the circuit scheduler (circuit.py)
 with a one-pass axis permutation (kernels.permute_qubits) that relabels
@@ -66,7 +67,11 @@ def _resolve_interpret(interpret, amps) -> bool:
 # TPU decompose into bf16 MXU passes: HIGHEST = 6 passes (full f32
 # accuracy), DEFAULT = 1 pass (bf16, ~1e-3 — too coarse for amplitudes).
 # The window pass is MXU-bound at HIGHEST (measured on v5e: rank-1 A+B
-# 4.45 ms vs a 1.3 ms HBM floor at 2^26 amps; rank-4 18.6 ms), so the
+# 4.45 ms vs a 1.3 ms HBM floor at 2^26 amps; rank-4 18.6 ms), so each
+# complex side product takes three real products, not four (Gauss's form,
+# _window_block_body): at 2^30 amps on one v5e a rank-1 dual pass fell
+# from 71.0 to 59.6 ms and a B-only pass from 44.1 to 35.4 ms, against a
+# 21.0 ms HBM floor (scripts/window_pass_timing.py).  The
 # "bf16_3x" mode implements the 3-pass split Mosaic's dot lowering lacks
 # (Precision.HIGH raises NotImplementedError): x@m = xh@mh + xh@ml + xl@mh
 # with xh/xl (mh/ml) the bf16 hi/lo halves of each f32 operand and f32
@@ -289,59 +294,61 @@ def _apply_swap_cluster_stack_jit(
     return out.reshape(in_shape)
 
 
+def _gauss_planes(mats):
+    """(R, 2, 128, 128) SoA matrices -> (R, 3, 128, 128) planes
+    [Re, Im, Re + Im]: the matrix operands of the three-product complex
+    form (_window_block_body), the sum formed once per pass here rather
+    than once per grid step in the kernel."""
+    return jnp.concatenate([mats, mats[:, :1] + mats[:, 1:]], axis=1)
+
+
 def _window_block_body(x, ma, mb, mask, rank, apply_a, apply_b, prec):
     """Shared window-pass algebra on one VMEM-resident 5-d value
-    (2, R, 128, M, 128) — window index on axis 2, lanes on axis 4, R/M
-    pure batch axes.  Used verbatim by both the single-pass kernel
+    (2, R, 128, M, 128) — window index on axis 2, lanes last, R/M pure
+    batch axes — or, where M = 1, the 4-d (2, R, 128, 128) block itself
+    (a unit M axis would pad each (1, 128) row to an (8, 128) tile).
+    Used verbatim by both the single-pass kernel
     (_window_kernel) and the megakernel (_mega_window_kernel) so the two
     routes issue IDENTICAL dot_generals in identical order and stay
-    bit-exact against each other (tests/test_megakernel.py pins this)."""
+    bit-exact against each other (tests/test_megakernel.py pins this).
+
+    Each complex side product M (xr + i xi) takes three real products
+    (Gauss's form, as BLAS cgemm3m): t1 = Mr xr, t2 = Mi xi,
+    t3 = (Mr + Mi)(xr + xi); re = t1 - t2, im = t3 - t1 - t2.  The
+    matrix operands arrive as _gauss_planes [Mr, Mi, Mr + Mi].  The rank
+    terms' final-side products sum before the one combine; the mask
+    multiplies after it.  A side whose flag is off is identity and costs
+    no product, so a mask-only pass is elementwise."""
     xr, xi = x[0], x[1]
-    if apply_a and apply_b:
-        # both sides: the lane-concat real rep keeps each side ONE
-        # 256-contraction (beats 4 separate 128-dots per side,
-        # measured both rounds)
-        xc0 = jnp.concatenate([xr, xi], axis=-1)     # (R, 128, M, 256)
+    if not (apply_a or apply_b):
+        rr, ri = xr, xi
+    else:
+        xs = xr + xi
+        # lanes: y[l'] = sum_l A[l',l] x[l]
+        da = (((xr.ndim - 1,), (1,)), ((), ()))
+        db = (((1,), (1,)), ((), ()))    # window axis, left-contracted
         acc = None
         for r in range(rank):
-            xc = _kdot(xc0, ma[r], (((3,), (0,)), ((), ())), prec)                                        # (R, 128, M, 256)
-            yr, yi = xc[..., :CLUSTER_DIM], xc[..., CLUSTER_DIM:]
-            # sublane op: left-contract the window axis (dim 1)
-            yc = jnp.concatenate([yr, yi], axis=1)   # (R, 256, M, 128)
-            out = _kdot(mb[r], yc, (((1,), (1,)), ((), ())), prec)                                        # (256, R, M, 128)
-            out = jnp.moveaxis(out, 0, 1)            # (R, 256, M, 128)
-            acc = out if acc is None else acc + out
-        rr, ri = acc[:, :CLUSTER_DIM], acc[:, CLUSTER_DIM:]
-    elif apply_b:
-        # B-only: separate-channel dots — skips the lane concat AND
-        # the lane slice the generic path paid for nothing
-        # (measured ~20-30% faster per pass at 26q)
-        rr = ri = None
-        for r in range(rank):
-            br, bi = mb[r, 0], mb[r, 1]
-            db = (((1,), (1,)), ((), ()))
-            pr = _kdot(br, xr, db, prec) - _kdot(bi, xi, db, prec)
-            pi = _kdot(br, xi, db, prec) + _kdot(bi, xr, db, prec)
-            pr = jnp.moveaxis(pr, 0, 1)              # (R, 128, M, 128)
-            pi = jnp.moveaxis(pi, 0, 1)
-            rr = pr if rr is None else rr + pr
-            ri = pi if ri is None else ri + pi
-    else:
-        # A-only: separate-channel right-dots on the lane axis
-        # (y[l'] = sum_l A[l',l] x[l] -> contract the matrix's col dim)
-        rr = ri = None
-        for r in range(rank):
-            ar, ai = ma[r, 0], ma[r, 1]
-            da = (((3,), (1,)), ((), ()))
-            pr = _kdot(xr, ar, da, prec) - _kdot(xi, ai, da, prec)
-            pi = _kdot(xr, ai, da, prec) + _kdot(xi, ar, da, prec)
-            rr = pr if rr is None else rr + pr
-            ri = pi if ri is None else ri + pi
+            yr, yi, ys = xr, xi, xs
+            if apply_a:
+                t = tuple(_kdot(y, ma[r, p], da, prec)
+                          for p, y in enumerate((yr, yi, ys)))
+                if apply_b:
+                    yr, yi = t[0] - t[1], t[2] - t[0] - t[1]
+                    ys = yr + yi
+            if apply_b:                  # (128, R, M, 128)
+                t = tuple(_kdot(mb[r, p], y, db, prec)
+                          for p, y in enumerate((yr, yi, ys)))
+            acc = t if acc is None else tuple(s + u for s, u in zip(acc, t))
+        rr, ri = acc[0] - acc[1], acc[2] - acc[0] - acc[1]
+        if apply_b:
+            rr, ri = jnp.moveaxis(rr, 0, 1), jnp.moveaxis(ri, 0, 1)
     if mask is not None:
-        mr = mask[0][:, None, :]                     # (128, 1, 128)
-        mi = mask[1][:, None, :]
+        shape = (CLUSTER_DIM,) * 2 if rr.ndim == 3 else (CLUSTER_DIM, 1,
+                                                         CLUSTER_DIM)
+        mr, mi = mask[0].reshape(shape), mask[1].reshape(shape)
         rr, ri = rr * mr - ri * mi, rr * mi + ri * mr
-    return jnp.stack([rr, ri], axis=0)               # (2, R, 128, M, 128)
+    return jnp.stack([rr, ri], axis=0)               # the shape of x
 
 
 def _window_kernel(rank, apply_a, apply_b, prec=jax.lax.Precision.HIGHEST,
@@ -352,19 +359,19 @@ def _window_kernel(rank, apply_a, apply_b, prec=jax.lax.Precision.HIGHEST,
     (2, R, 128, M, 128): R hi-axis blocks x M mid-axis blocks; both are
     pure batch axes of the two MXU contractions, so no in-kernel
     transposes are needed.  ``apply_a``/``apply_b`` skip the corresponding
-    matmul when that side of the window operator is identity (half the
-    FLOPs of a full pass).  ``with_mask`` appends one complex elementwise
-    multiply by a (2, 128, 128) (window x lane) mask — how diagonal
-    crossing gates (CZ/CPhase, and CNOT via its H-sandwich rewrite) are
-    applied at zero rank cost (circuit.fold_mask)."""
+    products when that side of the window operator is identity (half the
+    FLOPs of a full pass; none when both are off).  ``with_mask`` appends
+    one complex elementwise multiply by a (2, 128, 128) (window x lane)
+    mask — how diagonal crossing gates (CZ/CPhase, and CNOT via its
+    H-sandwich rewrite) are applied at zero rank cost (circuit.fold_mask)."""
 
     def kernel(a_ref, ma_ref, mb_ref, *rest):
         mask_ref, o_ref = (rest[0], rest[1]) if with_mask else (None, rest[0])
         xflat = a_ref[...]              # (2, R, 128, M*128) or (2, R, 128, M, 128)
-        x = xflat.reshape(
-            2, xflat.shape[1], CLUSTER_DIM,
-            -1, CLUSTER_DIM,
-        )                               # (2, R, 128, M, 128)
+        x = xflat
+        if xflat.shape[-1] != CLUSTER_DIM:
+            x = xflat.reshape(2, xflat.shape[1], CLUSTER_DIM, -1,
+                              CLUSTER_DIM)    # (2, R, 128, M, 128)
         res = _window_block_body(
             x, ma_ref, mb_ref,
             mask_ref[...] if with_mask else None,
@@ -376,7 +383,7 @@ def _window_kernel(rank, apply_a, apply_b, prec=jax.lax.Precision.HIGHEST,
 
 @partial(jax.jit,
          static_argnames=("num_qubits", "k", "apply_a", "apply_b",
-                          "block_amps", "interpret", "precision"),
+                          "interpret", "precision"),
          donate_argnums=0)
 def _apply_window_stack_jit(
     amps,
@@ -388,7 +395,6 @@ def _apply_window_stack_jit(
     k: int = SUBLANE_QUBITS,
     apply_a: bool = True,
     apply_b: bool = True,
-    block_amps: int = 8 * BLOCK_AMPS,
     interpret: bool | None = None,
     precision: str | None = None,
 ):
@@ -420,27 +426,30 @@ def _apply_window_stack_jit(
     # batch mid first — a block's contiguous HBM span per sublane row is
     # M*512 bytes (the trailing (mid, lane) axis is memory-contiguous), so
     # small M means descriptor-bound strided DMA (M=1 -> 512 B chunks);
-    # then batch hi with what remains.  Scale the total down with rank —
-    # the unrolled rank loop multiplies the scoped VMEM for temporaries.
-    # Empirical limits (16 MB scoped VMEM): rank-4 A+B overflows at 8
-    # blocks (18.4M) but fits at 4; rank-1 A+B overflows at 16 blocks
-    # (17.0M) but fits at 8; rank-1 B-only fits at 16 (fewer temporaries
-    # with the lane matmul skipped).
-    block_amps = max(BLOCK_AMPS, 2 * block_amps // rank)
+    # then batch hi with what remains.  The block count is the most the
+    # 16 MB scoped VMEM holds beside the unrolled rank loop's temporaries,
+    # from compiles of the three-product body for a described v5e
+    # (tests/test_chip_compile.py, 28 and 30 qubits): rank 1 fits 16
+    # blocks but a masked dual-side pass overflows there in the 5-d view;
+    # rank 2 overflows at 16; at rank 3-4 a single-side pass fits 8 and a
+    # dual-side one overflows at 8.  k = 8, 9 (mid 2, 4) pad each block's
+    # (M, 128) rows to 8 in the kernel: there rank 1 fits 8, rank 2 fits 8
+    # single-side and 4 dual-side, rank 3-4 fit 4.  Rank-1 passes take 16
+    # only as unmasked single-side passes in the 5-d view and 8 elsewhere:
+    # the sizes the chip timed (PERF.md §5); 16 elsewhere is untimed.
+    dual = apply_a and apply_b
+    if 1 < mid < 8:
+        nb = 8 if rank == 1 or (rank == 2 and not dual) else 4
+    elif rank == 1:
+        nb = 16 if mid >= 8 and apply_a != apply_b and mask is None else 8
+    else:
+        nb = 4 if dual and rank > 2 else 8
+    block_amps = nb * BLOCK_AMPS
     if n <= 21:
         # small states (<= 16 MB) can be VMEM-promoted wholesale by XLA
         # inside larger programs; an 8-block pass then overflows the 16 MB
         # scoped VMEM (measured 18.55M at n=20).  4 blocks always fit.
         block_amps = min(block_amps, 4 * BLOCK_AMPS)
-    if rank == 1 and (apply_a == apply_b or mask is not None or mid < 8):
-        # 16 blocks sit at/over the 16M scoped VMEM limit when extra
-        # temporaries are live: the dual-side kernel overflowed at 17.0M
-        # with the lane matmul, the separate-channel single-side kernels
-        # at 25.8M with a mask, and the single-side NON-five_d layout
-        # (mid < 8, e.g. k=7 B-only in the QFT bit reversal) at 19.0M —
-        # all capped at 8.  Only unmasked single-side passes in the 5-d
-        # layout keep 16 (fewer temporaries; compiles at <= 16M).
-        block_amps = min(block_amps, 8 * BLOCK_AMPS)
     # View choice is LAYOUT-critical: with mid >= 8 the 5-d view
     # (2, hi, 128, mid, 128) under the default T(8,128) tiling of its two
     # minor dims is PHYSICALLY IDENTICAL to the canonical k=7 view
@@ -451,9 +460,9 @@ def _apply_window_stack_jit(
     # dim, forcing XLA to insert a full-state retile copy (~4 ms at 26q)
     # at EVERY pass boundary (measured: a 26-pass plan spent ~60 ms in
     # such copies).  k in {8, 9} (mid 2, 4) keeps the 4-d view (the 5-d
-    # form would pad mid to 8, up to 4x memory), as do rank>2 passes whose
-    # VMEM budget cannot afford the 8-block minimum tile the 5-d layout
-    # requires (rank-4 A+B overflows scoped VMEM at 8 blocks).
+    # form would pad mid to 8, up to 4x memory), as do rank 3-4 dual-side
+    # passes, whose scoped VMEM cannot hold the 5-d layout's 8-block
+    # minimum tile.
     five_d = mid >= 8 and block_amps >= 8 * BLOCK_AMPS
     M = min(mid, max(1, block_amps // BLOCK_AMPS))
     if five_d and M % 8:
@@ -463,17 +472,9 @@ def _apply_window_stack_jit(
     R = min(hi, max(1, block_amps // (M * BLOCK_AMPS)))
     while hi % R:
         R //= 2
-    if apply_a and apply_b:
-        # dual-side kernel consumes the 256x256 real representations
-        ma = jax.vmap(lane_real_rep)(jnp.asarray(mats_a, amps.dtype))
-        mb = jax.vmap(sublane_real_rep)(jnp.asarray(mats_b, amps.dtype))
-        mat_dim = 2 * CLUSTER_DIM
-        mat_spec = (rank, mat_dim, mat_dim)
-    else:
-        # single-side kernels consume the raw SoA matrices
-        ma = jnp.asarray(mats_a, amps.dtype)
-        mb = jnp.asarray(mats_b, amps.dtype)
-        mat_spec = (rank, 2, CLUSTER_DIM, CLUSTER_DIM)
+    ma = _gauss_planes(jnp.asarray(mats_a, amps.dtype))
+    mb = _gauss_planes(jnp.asarray(mats_b, amps.dtype))
+    mat_spec = (rank, 3, CLUSTER_DIM, CLUSTER_DIM)
     with_mask = mask is not None
     if five_d:
         view = amps.reshape(2, hi, CLUSTER_DIM, mid, CLUSTER_DIM)
@@ -682,12 +683,11 @@ def apply_diagonal_canonical(amps, diag, *, num_qubits: int,
 def megakernel_mode() -> str:
     """QT_MEGAKERNEL knob: "on" forms megawin groups on every backend
     (interpret mode off the TPU — the CPU test/bench arm); anything else
-    is "off", the default.  On a v5e the Mosaic compiler refuses
-    the megakernel for a k=7 single-side member (a layout with an
-    implicit dimension) and overflows scoped VMEM for rank-2 single-side
-    members at 8 rows, both at row caps megawin_row_cap admits
-    (tests/test_chip_compile.py compiles the kernel for a described
-    v5e), so the default plans per-pass window kernels only."""
+    is "off", the default.  For a described v5e the groups
+    tests/test_chip_compile.py tries compile and run in place at 30
+    qubits (rank 1-2 on 8 rows, rank 4 on 4, groups opening with a k = 7
+    member), but no chip measurement has shown a group beating its
+    per-pass kernels, so the default plans per-pass window kernels only."""
     import os
 
     raw = os.environ.get("QT_MEGAKERNEL", "off").strip().lower()
@@ -702,11 +702,11 @@ def megakernel_planning() -> bool:
 
 
 def megawin_row_cap(rank: int, num_qubits: int) -> int:
-    """Largest VMEM block-row grouping a sub-pass of this rank tolerates,
-    mirroring the empirical scoped-VMEM rules of _apply_window_stack_jit
-    (rank-1 dual-side overflows 16 MB at 16 rows, fits at 8; rank-4 fits
-    at 4; n <= 21 states risk wholesale XLA VMEM promotion, cap 4).  A
-    group's G = 2^(kmax-7) must stay <= min over its sub-passes."""
+    """Largest VMEM block-row grouping a sub-pass of this rank tolerates:
+    8 rows at rank 1-2 and 4 at rank 3-4 compile for a described v5e at
+    30 qubits (tests/test_chip_compile.py); n <= 21 states risk wholesale
+    XLA VMEM promotion, cap 4.  A group's G = 2^(kmax-7) must stay <= min
+    over its sub-passes."""
     cap = 8 if rank <= 2 else 4
     if num_qubits <= 21:
         cap = min(cap, 4)
@@ -738,8 +738,10 @@ def _mega_window_kernel(spec, prec=jax.lax.Precision.HIGHEST):
             wg = 1 << (k - LANE_QUBITS)      # window bits on the row axis
             whi = CLUSTER_DIM >> (k - LANE_QUBITS)  # ... on the sublanes
             ghi = g_rows // wg
-            x5 = x.reshape(2, ghi, wg, whi, wg, CLUSTER_DIM)
-            x5 = x5.reshape(2, ghi, CLUSTER_DIM, wg, CLUSTER_DIM)
+            x5 = x                           # k = 7: the block as it is
+            if wg > 1:
+                x5 = x.reshape(2, ghi, wg, whi, wg, CLUSTER_DIM)
+                x5 = x5.reshape(2, ghi, CLUSTER_DIM, wg, CLUSTER_DIM)
             res = _window_block_body(x5, ma_ref, mb_ref, mask,
                                      rank, apply_a, apply_b, prec)
             x = res.reshape(2, g_rows, CLUSTER_DIM, CLUSTER_DIM)
@@ -781,22 +783,13 @@ def _apply_megawin_jit(
     in_specs = [state_spec]
     operands = []
     ai = 0
-    for (k, rank, apply_a, apply_b, with_mask) in spec:
-        a = jnp.asarray(arrays[ai], amps.dtype)
-        b = jnp.asarray(arrays[ai + 1], amps.dtype)
+    for (_k, rank, _a, _b, with_mask) in spec:
+        mat_spec = pl.BlockSpec((rank, 3, CLUSTER_DIM, CLUSTER_DIM),
+                                lambda i: (0, 0, 0, 0))
+        in_specs += [mat_spec, mat_spec]
+        operands += [_gauss_planes(jnp.asarray(arrays[ai + j], amps.dtype))
+                     for j in (0, 1)]
         ai += 2
-        if apply_a and apply_b:
-            # dual-side passes consume the 256x256 real representations
-            ma, mb = jax.vmap(lane_real_rep)(a), jax.vmap(sublane_real_rep)(b)
-            mat_spec = (rank, 2 * CLUSTER_DIM, 2 * CLUSTER_DIM)
-        else:
-            # single-side passes consume the raw SoA matrices
-            ma, mb = a, b
-            mat_spec = (rank, 2, CLUSTER_DIM, CLUSTER_DIM)
-        zmap = lambda i, _d=len(mat_spec): (0,) * _d
-        in_specs += [pl.BlockSpec(mat_spec, zmap),
-                     pl.BlockSpec(mat_spec, zmap)]
-        operands += [ma, mb]
         if with_mask:
             in_specs.append(pl.BlockSpec((2, CLUSTER_DIM, CLUSTER_DIM),
                                          lambda i: (0, 0, 0)))

@@ -138,6 +138,103 @@ def _mats(sharding, rank=1):
                                 sharding=sharding)
 
 
+# (num_qubits, rank, k, apply_a, apply_b, with_mask) of window passes:
+# every rank-1 pass the 30-qubit benchmark plans issue (the random-layer
+# drain's dual-side, B-only, A-only and mask-only passes, masked or not,
+# from the 4-d k = 7 view to k = 23, where one block spans the whole hi
+# axis; the QFT's folded low layers and bit reversal), then rank 2 and 4
+# (crossing controlled rotations; SWAPs and general two-qubit gates) in
+# each view: the canonical 4-d block (k = 7) and the 5-d view (k = 10,
+# 23) at 30 qubits, and ranks 1-4 in the 4-d view of k = 8, 9 (mid 2, 4)
+# at 28, where that view's retile copy of the state fits beside it
+_SIDES = ((True, True), (False, True), (True, False))
+WINDOW_CASES = (
+    [(N, 1) + sig for sig in sorted(
+        {(k, True, True, False) for k in (7, 10, 12, 14, 16, 18, 22, 23)}
+        | {(k, True, True, True) for k in (7, 23)}
+        | {(k, False, True, False)
+           for k in (7, 10, 11, 12, 14, 16, 17, 19, 21, 22, 23)}
+        | {(k, False, True, True) for k in (7, 19, 20)}
+        | {(18, True, False, True)}
+        | {(k, False, False, True) for k in (7, 17, 23)})]
+    + [(N, rank, k, a, b, m) for rank in (2, 4) for k in (7, 10, 23)
+       for (a, b) in _SIDES for m in (False, True)]
+    + [(28, rank, k, a, b, m) for rank in (1, 2, 4) for k in (8, 9)
+       for (a, b) in _SIDES for m in (False, True)])
+
+
+@pytest.mark.parametrize("n,rank,k,apply_a,apply_b,with_mask",
+                         WINDOW_CASES)
+def test_window_pass_signatures_fit(one_chip, n, rank, k, apply_a, apply_b,
+                                    with_mask):
+    """Each pass compiles in its three-product form within the scoped
+    VMEM at the block size _apply_window_stack_jit picks for it and
+    writes the state in place.  A rank 3-4 dual-side pass takes 4
+    blocks, so at k > 7 it runs on the 4-d view, and at 30 qubits that
+    view's retile copy of the 8 GiB state does not fit the HBM."""
+    state_bytes = 2 * (1 << n) * 4
+    m = _mats(one_chip, rank)
+    mask = jax.ShapeDtypeStruct((2, 128, 128), jnp.float32,
+                                sharding=one_chip)
+
+    def f(x, a, b, *mk):
+        return fused._apply_window_stack_jit(
+            x, a, b, *mk, num_qubits=n, k=k, apply_a=apply_a,
+            apply_b=apply_b, interpret=False)
+
+    args = (m, m, mask) if with_mask else (m, m)
+    lowered = jax.jit(f, donate_argnums=0).lower(_state(one_chip, n), *args)
+    if n == N and rank > 2 and apply_a and apply_b and k > 7:
+        with pytest.raises(Exception, match="hbm"):
+            lowered.compile()
+        return
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    if k in (8, 9):
+        ma = compiled.memory_analysis()
+        assert ma.temp_size_in_bytes < state_bytes + SMALL
+        assert ma.alias_size_in_bytes == state_bytes
+    else:
+        _in_place(compiled, state_bytes)
+
+
+# megawin groups at the row caps megawin_row_cap gives each rank: rank 2
+# on 8 rows (k = 10) single- and dual-side, masked or not; groups that
+# open with a k = 7 member; rank 4 on 4 rows (k = 9)
+MEGAWIN_SPECS_30Q = (
+    ((10, 2, False, True, False),),
+    ((10, 2, False, True, True),),
+    ((10, 2, True, False, True),),
+    ((10, 2, True, True, True),),
+    ((7, 2, False, True, False), (10, 2, False, True, False)),
+    ((7, 1, True, True, True), (8, 1, False, True, True),
+     (10, 1, True, True, True)),
+    ((9, 4, True, True, True),),
+    ((9, 4, False, True, True),),
+)
+
+
+@pytest.mark.parametrize("spec", MEGAWIN_SPECS_30Q)
+def test_megawin_groups_in_place_30q(one_chip, spec):
+    """A megawin group compiles within the scoped VMEM and sweeps the
+    8 GiB state in place."""
+    ops = []
+    for (_k, rank, _a, _b, with_mask) in spec:
+        ops += [_mats(one_chip, rank)] * 2
+        if with_mask:
+            ops.append(jax.ShapeDtypeStruct((2, 128, 128), jnp.float32,
+                                            sharding=one_chip))
+
+    def f(x, *ops):
+        return fused._apply_megawin_jit(x, *ops, num_qubits=N, spec=spec,
+                                        interpret=False)
+
+    compiled = jax.jit(f, donate_argnums=0).lower(
+        _state(one_chip), *ops).compile()
+    _in_place(compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("kind", ["fused", "swapfused", "sigma_swap"])
 def test_other_in_place_passes_30q(one_chip, kind):
     """The remaining plan ops the governor prices at no extra state
@@ -278,9 +375,11 @@ def test_state_fill_on_device_30q(one_chip):
 
 
 def test_megakernel_refused_on_v5e_and_not_planned(one_chip, monkeypatch):
-    """The v5e compiler refuses a megawin group with a k=7 single-side
-    member, a grouping megawin_row_cap admits — so the default plans
-    none, on the TPU too."""
+    """A megawin group with a k=7 single-side member, once refused by the
+    v5e compiler (the member's 5-d block had a unit, implicit dimension),
+    compiles now that a k=7 member works on its 4-d block, and sweeps the
+    state in place; the default still plans no groups, on the TPU too:
+    no chip measurement has shown a group beating its per-pass kernels."""
     n = 17
     spec = ((fused.LANE_QUBITS, 1, False, True, False),
             (fused.LANE_QUBITS + 1, 1, False, True, False))
@@ -290,8 +389,10 @@ def test_megakernel_refused_on_v5e_and_not_planned(one_chip, monkeypatch):
         return fused._apply_megawin_jit(x, *ops, num_qubits=n, spec=spec,
                                         interpret=False)
 
-    with pytest.raises(Exception, match="implicit dimension"):
-        jax.jit(f).lower(_state(one_chip, n), m, m, m, m).compile()
+    compiled = jax.jit(f, donate_argnums=0).lower(
+        _state(one_chip, n), m, m, m, m).compile()
+    _in_place(compiled, 2 * (1 << n) * 4)
+    assert "tpu_custom_call" in compiled.as_text()
     monkeypatch.delenv("QT_MEGAKERNEL", raising=False)
     assert not fused.megakernel_planning()
 
